@@ -29,10 +29,7 @@ from repro.train.steps import init_train_state, make_train_step
 
 
 def _hlo_flops(compiled) -> float:
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # older jax returns a 1-elem list of dicts
-        ca = ca[0] if ca else {}
-    return ca.get("flops", 0)
+    return compiled.cost_analysis().get("flops", 0)
 
 
 def main() -> None:

@@ -7,6 +7,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 SECTIONS = [
     ("table1_forwarding", "paper Table 1: native vs forwarding x N"),
     ("fig4_pushdown", "paper Fig 3/4: pushdown vs client-side queries"),
@@ -21,6 +23,7 @@ SECTIONS = [
 
 
 def main() -> None:
+    enable_compile_cache()
     want = set(sys.argv[1:])
     failures = []
     for name, desc in SECTIONS:

@@ -28,6 +28,7 @@ from repro.core import GlobalVOL, make_store
 from repro.core.partition import PartitionPolicy
 from repro.data.corpus import CorpusSpec, build_corpus
 from repro.data.pipeline import ObjectDataLoader
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.archs import build_model
 from repro.train.optimizer import OptConfig
 from repro.train.trainer import Trainer, TrainerConfig
@@ -58,6 +59,52 @@ def make_cfg(p: dict) -> ArchConfig:
         param_dtype=jnp.float32, compute_dtype=jnp.float32)
 
 
+def build_store(p: dict, cfg: ArchConfig, steps: int, seed: int):
+    """An 8-OSD store holding the bitpacked token corpus (~4 epochs of
+    ``steps``) mapped to objects through the VOL."""
+    store = make_store(8, replicas=2)
+    vol = GlobalVOL(store)
+    n_seqs = max(steps * p["batch"] // 4, 512)  # ~4 epochs
+    build_corpus(vol, CorpusSpec(n_seqs=n_seqs, seq_len=p["seq"],
+                                 vocab_size=cfg.vocab_size, seed=seed),
+                 policy=PartitionPolicy(target_object_bytes=2 << 20,
+                                        max_object_bytes=16 << 20))
+    print(f"[e2e] corpus: {n_seqs} x {p['seq']} tokens in "
+          f"{store.stats()['n_objects']} objects")
+    return store, vol
+
+
+def make_trainer(p: dict, cfg: ArchConfig, store, vol, steps: int,
+                 seed: int, *, ckpt_every: int | None = None):
+    """The packed-ingest Trainer over the store's corpus: the loader
+    ships packed words (zero-decode ``select_packed``) and the unpack
+    runs inside the compiled step.  Returns (trainer, loader)."""
+    model = build_model(cfg, remat="none")
+    loader = ObjectDataLoader(vol, "corpus", global_batch=p["batch"],
+                              seed=seed, packed=True, prefetch=2,
+                              hedge_timeout_s=0.5)
+    trainer = Trainer(
+        model, loader, store,
+        opt=OptConfig(lr=6e-4, warmup_steps=max(steps // 20, 5),
+                      total_steps=steps),
+        cfg=TrainerConfig(total_steps=steps,
+                          ckpt_every=ckpt_every or max(steps // 4, 10),
+                          log_every=max(steps // 20, 5),
+                          packed_ingest=True))
+    return trainer, loader
+
+
+def kill_and_recover(store, step: int) -> dict:
+    """Fail-stop the first up OSD and re-replicate from the survivors."""
+    victim = store.cluster.up_osds[0]
+    store.fail_osd(victim)
+    rec = store.recover()
+    print(f"[e2e] step {step}: killed {victim}; recovery moved "
+          f"{rec['objects_moved']} replicas, lost "
+          f"{rec['objects_lost']}")
+    return rec
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", choices=PRESETS, default="tiny")
@@ -65,26 +112,15 @@ def main() -> None:
     ap.add_argument("--kill-osd-at", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     p = PRESETS[args.preset]
 
     cfg = make_cfg(p)
     print(f"[e2e] {args.preset}: {cfg.param_count() / 1e6:.1f}M params")
 
-    store = make_store(8, replicas=2)
-    vol = GlobalVOL(store)
-    n_seqs = max(args.steps * p["batch"] // 4, 512)  # ~4 epochs
-    build_corpus(vol, CorpusSpec(n_seqs=n_seqs, seq_len=p["seq"],
-                                 vocab_size=cfg.vocab_size,
-                                 seed=args.seed),
-                 policy=PartitionPolicy(target_object_bytes=2 << 20,
-                                        max_object_bytes=16 << 20))
-    print(f"[e2e] corpus: {n_seqs} x {p['seq']} tokens in "
-          f"{store.stats()['n_objects']} objects")
-
-    model = build_model(cfg, remat="none")
-    loader = ObjectDataLoader(vol, "corpus", global_batch=p["batch"],
-                              seed=args.seed, packed=True, prefetch=2,
-                              hedge_timeout_s=0.5)
+    store, vol = build_store(p, cfg, args.steps, args.seed)
+    trainer, loader = make_trainer(p, cfg, store, vol, args.steps,
+                                   args.seed)
     kill_at = args.kill_osd_at or args.steps // 2
 
     path = pathlib.Path(__file__).resolve().parents[1] / "results"
@@ -104,24 +140,11 @@ def main() -> None:
 
     def on_step(step: int) -> None:
         if step == kill_at:
-            victim = store.cluster.up_osds[0]
-            store.fail_osd(victim)
-            rec = store.recover()
-            print(f"[e2e] step {step}: killed {victim}; recovery moved "
-                  f"{rec['objects_moved']} replicas, lost "
-                  f"{rec['objects_lost']}")
+            kill_and_recover(store, step)
         if step % 10 == 0:
             write_partial(trainer.history)
 
-    trainer = Trainer(
-        model, loader, store,
-        opt=OptConfig(lr=6e-4, warmup_steps=max(args.steps // 20, 5),
-                      total_steps=args.steps),
-        cfg=TrainerConfig(total_steps=args.steps,
-                          ckpt_every=max(args.steps // 4, 10),
-                          log_every=max(args.steps // 20, 5),
-                          packed_ingest=True))
-    state = trainer.run(on_step=on_step)
+    trainer.run(on_step=on_step)
     loader.close()
 
     losses = [h["loss"] for h in trainer.history]

@@ -16,7 +16,7 @@ Codecs:
   bitpack<b>    — planar bitpack for unsigned ints < 2**b: each group of
                   32 values becomes b uint32 words, word k holding bit k
                   of all 32 values.  TPU-friendly: decode is shift/mask
-                  vector ops only (see kernels/codec) so the *storage
+                  vector ops only (see kernels/bitunpack) so the *storage
                   side* decompression can run on the device that owns the
                   shard — the paper's `compress` offload adapted to TPU.
 
@@ -28,7 +28,6 @@ from __future__ import annotations
 
 import json
 import struct
-import warnings
 import zlib
 from typing import Mapping
 
@@ -60,7 +59,7 @@ def content_digest(blob: bytes) -> int:
 
 
 # --------------------------------------------------------------------------
-# planar bitpack codec (numpy reference; kernels/codec has the Pallas twin)
+# planar bitpack codec (numpy reference; kernels/bitunpack has the Pallas twin)
 # --------------------------------------------------------------------------
 
 
@@ -159,11 +158,12 @@ def bitpack_decode(words: np.ndarray, bits: int, n: int) -> np.ndarray:
 # --------------------------------------------------------------------------
 
 # "auto": the Pallas kernel (kernels/bitunpack) decodes bitpack columns
-# whenever a jax *device* backend (tpu/gpu) is selected — the storage-
-# side decode runs on the accelerator that owns the shard; on CPU (or
-# with no jax at all) the numpy butterfly codec is used.  "device" and
-# "numpy" force one side (tests force "device" to exercise the kernel in
-# interpret mode on CPU and assert bit-exactness).
+# whenever the jax backend is a TPU — the storage-side decode runs on the
+# accelerator that owns the shard, compiled, and any import, lowering or
+# runtime error of the kernel propagates out of the scan; on any other
+# backend the numpy butterfly codec is used.  "device" and "numpy" force
+# one side (tests force "device" to exercise the kernel in interpret
+# mode on CPU and assert bit-exactness).
 _BITUNPACK_MODE = "auto"
 _bitunpack_impl = None  # resolved lazily; None = not resolved yet
 
@@ -180,48 +180,17 @@ def set_bitunpack_backend(mode: str) -> None:
 
 def _resolve_bitunpack():
     global _bitunpack_impl
-    if _bitunpack_impl is not None:
-        return _bitunpack_impl
-    want_device = _BITUNPACK_MODE == "device"
-    if _BITUNPACK_MODE == "auto":
-        try:
+    if _bitunpack_impl is None:
+        want_device = _BITUNPACK_MODE == "device"
+        if _BITUNPACK_MODE == "auto":
             import jax
-            want_device = jax.default_backend() in ("tpu", "gpu")
-        except Exception:
-            want_device = False
-    impl = bitpack_decode
-    if want_device:
-        try:
+            want_device = jax.default_backend() == "tpu"
+        if want_device:
             from repro.kernels.bitunpack import bitunpack_words
-            impl = bitunpack_words
-        except Exception:
-            if _BITUNPACK_MODE == "device":
-                raise  # forced backend: a missing kernel must be loud
-            impl = bitpack_decode  # auto: no jax/pallas -> numpy fallback
-    _bitunpack_impl = impl
-    return impl
-
-
-def _bitunpack_dispatch(words, bits: int, n: int) -> np.ndarray:
-    """Decode through the selected backend.  In "auto" mode a device
-    kernel that fails at call time (lowering/runtime error on this
-    backend) pins the numpy fallback for the rest of the process, with
-    a warning — a scan must never die on a codec *routing* choice.  In
-    forced "device" mode the error propagates: tests force that mode to
-    assert the kernel actually ran, so a silent fallback would let a
-    broken kernel pass green against the numpy path."""
-    global _bitunpack_impl
-    impl = _resolve_bitunpack()
-    if impl is not bitpack_decode:
-        try:
-            return impl(words, bits, n)
-        except Exception as e:
-            if _BITUNPACK_MODE == "device":
-                raise
-            warnings.warn(f"device bitunpack failed ({e!r}); "
-                          "pinning numpy fallback", RuntimeWarning)
+            _bitunpack_impl = bitunpack_words
+        else:
             _bitunpack_impl = bitpack_decode
-    return bitpack_decode(words, bits, n)
+    return _bitunpack_impl
 
 
 # --------------------------------------------------------------------------
@@ -261,7 +230,7 @@ def _decode_column(buf, codec: str, dtype: str,
     if codec.startswith("bitpack"):
         bits = int(codec[len("bitpack"):])
         words = np.frombuffer(buf, dtype=np.uint32)
-        return _bitunpack_dispatch(words, bits, n).astype(dtype).reshape(
+        return _resolve_bitunpack()(words, bits, n).astype(dtype).reshape(
             shape)
     raise ValueError(f"unknown codec {codec!r}")
 

@@ -609,8 +609,8 @@ def run_pipeline(blob: bytes, ops: list[ObjOp], encode: bool = True) -> Any:
     median / sketch cols + projection — :func:`required_columns`) and
     pushed into block decoding, so a filter→agg scan never decodes
     untouched columns (col layout).  Bitpack columns decode through the
-    Pallas kernel (``kernels/bitunpack``) when a jax device backend is
-    selected, with the numpy butterfly codec as the bit-exact fallback
+    compiled Pallas kernel (``kernels/bitunpack``) on a TPU backend and
+    through the bit-exact numpy butterfly codec elsewhere
     (``format.set_bitunpack_backend``).
 
     ``encode=False`` returns a table-out result as the raw column dict

@@ -10,7 +10,7 @@ O(data) -> O(result) traffic reduction, visible directly in the
 collective-bytes roofline term of the compiled HLO.
 
 ``unpack_bitpacked`` is the storage-side *decompress* offload: objects
-hold planar-bitpacked tokens (core.format codec, kernels/codec Pallas
+hold planar-bitpacked tokens (core.format codec, kernels/bitunpack Pallas
 twin); the unpack runs shard-locally inside the compiled train step, so
 the host->device and HBM input path carries b/32 of the raw bytes.
 """
@@ -22,7 +22,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding as shd
@@ -34,7 +33,7 @@ _PRED = {
 
 
 # --------------------------------------------------------------------------
-# codec offload: planar bitunpack (pure-jnp; kernels/codec has the Pallas
+# codec offload: planar bitunpack (pure-jnp; kernels/bitunpack has the Pallas
 # version — this one is the GSPMD-partitionable reference the steps use)
 # --------------------------------------------------------------------------
 
@@ -103,12 +102,12 @@ def pushdown_filter_aggregate(values: jax.Array, filter_col: jax.Array,
     dp = rules.dp_axes if len(rules.dp_axes) > 1 else rules.dp_axes[0]
     fn = functools.partial(_partial_filter_agg, cmp=cmp,
                            threshold=threshold, dp_axes=rules.dp_axes)
-    return shard_map(
+    return jax.shard_map(
         lambda v, f: fn(v, f),
         mesh=rules.mesh,
         in_specs=(P(dp), P(dp)),
         out_specs={k: P() for k in ("sum", "count", "min", "max")},
-        check_rep=False,
+        check_vma=False,
     )(values, filter_col)
 
 
@@ -132,5 +131,5 @@ def shard_local(fn: Callable, *, out_specs, in_axes: str = "dp"):
         return fn
     dp = rules.dp_axes if len(rules.dp_axes) > 1 else rules.dp_axes[0]
     spec = P(dp) if in_axes == "dp" else P(*in_axes)
-    return shard_map(fn, mesh=rules.mesh,
-                     in_specs=spec, out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=rules.mesh,
+                         in_specs=spec, out_specs=out_specs, check_vma=False)

@@ -49,14 +49,10 @@ def unpack_tokens(packed: jax.Array, *, use_pallas: bool = False,
         if G % 4:
             raise ValueError(f"use_pallas needs G % 4 == 0 "
                              f"(128-lane rows), got G={G}")
-        from repro.kernels.bitunpack import bitunpack, pad_to_grid
-        rows = B * (G // 4)
-        bm, padded = pad_to_grid(rows)
-        w = packed.reshape(rows, 4, bits)
-        if padded != rows:
-            w = jnp.pad(w, ((0, padded - rows), (0, 0), (0, 0)))
-        vals = bitunpack(w, bits=bits, block_r=bm, interpret=interpret)
-        return vals[:rows].reshape(B, G * 32)
+        from repro.kernels.bitunpack import bitunpack
+        vals = bitunpack(packed.reshape(B * (G // 4), 4, bits), bits=bits,
+                         interpret=interpret)
+        return vals.reshape(B, G * 32)
     return unpack_bitpacked(packed, bits)
 
 

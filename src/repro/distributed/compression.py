@@ -17,7 +17,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding as shd
@@ -172,11 +171,11 @@ def make_compressed_grad_sync(rules: "shd.MeshRules", logical_specs):
                             is_leaf=lambda s: isinstance(s, P))
 
     def sync(grads, err):
-        return shard_map(
+        return jax.shard_map(
             compressed_pod_allreduce, mesh=mesh,
             in_specs=(resolved, resolved),
             out_specs=(resolved, resolved),
-            check_rep=False,
+            check_vma=False,
         )(grads, err)
 
     return sync

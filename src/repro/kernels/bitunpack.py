@@ -6,68 +6,122 @@ per output row so the output tile is 128-lane aligned for the VPU:
 
   words  (R, 4, b)  uint32   ->   values (R, 128) int32
 
-Tiling: a (BLOCK_R, 4, b) word tile is (BLOCK_R * 4 * b * 4) bytes of
-VMEM; with BLOCK_R=256 and b=17 that's ~70 KiB in + 128 KiB out — well
-inside the ~16 MiB VMEM budget, leaving room for double buffering.  The
-unpack is shift/mask/sum VPU work with zero MXU involvement, so it
-overlaps cleanly with neighbouring matmul stages when fused into a step.
+The kernel sees each row as its 4*b words side by side (a free reshape
+of the same bytes), widens every group to a 32-lane segment (b words,
+then zero planes), and decodes with the same 32x32 bit transpose as the
+numpy codec (``format._bit_transpose32``): five masked shift-swap
+stages, with the partner word of each stage one lane roll away.  Only
+32-bit integer VPU ops and lane rolls, no reductions and no MXU work.
+
+Tiling: a (BLOCK_R, 4*b) word tile is BLOCK_R * 512 bytes of VMEM once
+padded to 128 lanes, and the (BLOCK_R, 128) output tile the same; with
+BLOCK_R=256 that is 128 KiB each, well inside the VMEM budget with
+double buffering.
 """
 
 from __future__ import annotations
 
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
 
 DEFAULT_BLOCK_R = 256
+
+# (swap distance, mask) per stage of the 32x32 bit transpose — the same
+# stages as ``format._BUTTERFLY``; every mask fits a positive int32
+_STAGES = ((16, 0x0000FFFF), (8, 0x00FF00FF), (4, 0x0F0F0F0F),
+           (2, 0x33333333), (1, 0x55555555))
 
 
 def pad_to_grid(rows: int, block_r: int = DEFAULT_BLOCK_R
                 ) -> tuple[int, int]:
     """Choose (block_r, padded_rows) for an R-row launch: the grid-step
     count comes from ``block_r``, then the block height is rebalanced to
-    ceil(rows / n_blocks), so padding is bounded by n_blocks - 1 rows —
-    padding straight up to a ``block_r`` multiple would nearly double
-    the kernel work at rows = block_r + 1."""
+    ceil(rows / n_blocks) rounded up to a multiple of 8 (the TPU's
+    sublane tile; a single block spans the whole array and needs no
+    rounding), so padding stays under 8 * n_blocks rows — padding
+    straight up to a ``block_r`` multiple would nearly double the
+    kernel work at rows = block_r + 1."""
     n_blocks = max(1, -(-rows // block_r))
+    if n_blocks == 1:
+        return rows, rows
     bm = -(-rows // n_blocks)
+    bm = -(-bm // 8) * 8
     return bm, n_blocks * bm
 
 
 def _bitunpack_kernel(w_ref, o_ref, *, bits: int):
-    w = w_ref[...]                                  # (bm, 4, bits) uint32
-    lane = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 1, 32), 3)
-    sel = (w[..., None] >> lane) & jnp.uint32(1)    # (bm, 4, bits, 32)
-    weight = (jnp.uint32(1) << jnp.arange(bits, dtype=jnp.uint32)
-              )[None, None, :, None]
-    vals = jnp.sum(sel * weight, axis=2, dtype=jnp.uint32)  # (bm, 4, 32)
-    bm = vals.shape[0]
-    o_ref[...] = vals.reshape(bm, 128).astype(jnp.int32)
+    w = w_ref[...]                                  # (bm, 4*bits) int32
+    bm = w.shape[0]
+    parts = []
+    for g in range(4):                              # group g -> lanes 32g..
+        parts.append(w[:, g * bits:(g + 1) * bits])
+        if bits < 32:
+            parts.append(jnp.zeros((bm, 32 - bits), jnp.int32))
+    x = jnp.concatenate(parts, axis=1)              # (bm, 128): lane 32g+k
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bm, 128), 1)
+    for j, m in _STAGES:
+        up = pltpu.roll(x, 128 - j, 1)              # lane l <- x[l + j]
+        down = pltpu.roll(x, j, 1)                  # lane l <- x[l - j]
+        t_lo = (jax.lax.shift_right_logical(x, j) ^ up) & m
+        t_hi = (jax.lax.shift_right_logical(down, j) ^ x) & m
+        x = jnp.where((lane & j) == 0, x ^ (t_lo << j), x ^ t_hi)
+    o_ref[...] = x
 
 
 def bitunpack(words: jax.Array, *, bits: int,
               block_r: int = DEFAULT_BLOCK_R,
               interpret: bool = False) -> jax.Array:
-    """(R, 4, bits) uint32 -> (R, 128) int32 via pallas_call."""
+    """(R, 4, bits) uint32 -> (R, 128) int32 via pallas_call; any R
+    (rows are padded up to the grid ``pad_to_grid`` picks and sliced
+    back off)."""
     R = words.shape[0]
-    if words.shape[1:] != (4, bits):
+    if words.shape[1:] != (4, bits) or not 1 <= bits <= 32:
         raise ValueError(f"want (R, 4, {bits}), got {words.shape}")
-    bm = min(block_r, R)
-    if R % bm:
-        raise ValueError(f"R={R} not divisible by block_r={bm}")
-    grid = (R // bm,)
-    return pl.pallas_call(
+    bm, padded = pad_to_grid(R, block_r)
+    w = jax.lax.bitcast_convert_type(words, jnp.int32).reshape(R, 4 * bits)
+    if padded != R:
+        w = jnp.pad(w, ((0, padded - R), (0, 0)))
+    out = pl.pallas_call(
         functools.partial(_bitunpack_kernel, bits=bits),
-        grid=grid,
-        in_specs=[pl.BlockSpec((bm, 4, bits), lambda i: (i, 0, 0))],
+        grid=(padded // bm,),
+        in_specs=[pl.BlockSpec((bm, 4 * bits), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, 128), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, 128), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((padded, 128), jnp.int32),
         interpret=interpret,
-    )(words)
+        name="bitunpack",
+    )(w)
+    return out[:R] if padded != R else out
+
+
+_bitunpack_jit = jax.jit(bitunpack,
+                         static_argnames=("bits", "block_r", "interpret"))
+
+# decode counters of the host adapter: calls, calls run in interpret
+# mode, and the distinct (rows, bits) launches — on a TPU each new one
+# is a Mosaic compile
+_stats_lock = threading.Lock()
+_stats = {"calls": 0, "interpret_calls": 0}
+_launch_shapes: set[tuple[int, int]] = set()
+
+
+def decode_stats() -> dict:
+    """Snapshot of the host adapter's counters (see ``bitunpack_words``)."""
+    with _stats_lock:
+        return dict(_stats, shapes=len(_launch_shapes))
+
+
+def reset_decode_stats() -> None:
+    with _stats_lock:
+        _stats.update(calls=0, interpret_calls=0)
+        _launch_shapes.clear()
 
 
 def bitunpack_words(words: np.ndarray, bits: int, n: int, *,
@@ -76,9 +130,9 @@ def bitunpack_words(words: np.ndarray, bits: int, n: int, *,
 
     Host-side adapter for the storage scan path
     (``format._decode_column`` / ``objclass.run_pipeline``): pads the
-    group count up to a legal (R, 4, bits) tile, runs the kernel on the
-    selected jax backend (interpret mode on CPU, so the exact code path
-    stays testable without a TPU), and slices the padding back off.
+    group count up to a legal (R, 4, bits) grid, runs the kernel on the
+    selected jax backend (compiled on a TPU, interpreted elsewhere — see
+    ``kernels.interpret_mode``), and slices the padding back off.
     Bit-exact with ``format.bitpack_decode`` — the zero pad groups decode
     to zeros and are dropped.
     """
@@ -87,13 +141,17 @@ def bitunpack_words(words: np.ndarray, bits: int, n: int, *,
     if n_groups == 0:
         return np.zeros((0,), np.uint32)[:n]
     rows = -(-n_groups // 4)                    # 4 groups per 128-lane row
-    bm, rows = pad_to_grid(rows)
+    _, rows = pad_to_grid(rows)
     if rows * 4 != n_groups:
         padded = np.zeros((rows * 4, bits), np.uint32)
         padded[:n_groups] = w
         w = padded
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    vals = bitunpack(jnp.asarray(w.reshape(rows, 4, bits)), bits=bits,
-                     block_r=bm, interpret=interpret)
-    return np.asarray(vals).astype(np.uint32).ravel()[:n]
+        interpret = interpret_mode()
+    with _stats_lock:
+        _stats["calls"] += 1
+        _stats["interpret_calls"] += int(interpret)
+        _launch_shapes.add((rows, bits))
+    vals = _bitunpack_jit(jnp.asarray(w.reshape(rows, 4, bits)), bits=bits,
+                          interpret=interpret)
+    return np.asarray(vals).view(np.uint32).ravel()[:n]

@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute with ``interpret=True``; on a
-real TPU backend the same call sites compile to Mosaic.  ``_interpret()``
-keys off the default backend so call sites never branch.
+On a TPU backend the kernels compile to Mosaic; elsewhere they run with
+``interpret=True``.  ``kernels.interpret_mode`` keys off the default
+backend so call sites never branch.
 """
 
 from __future__ import annotations
@@ -15,10 +15,7 @@ import jax.numpy as jnp
 from repro.kernels import bitunpack as _bu
 from repro.kernels import block_agg as _ba
 from repro.kernels import filter_agg as _fa
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.kernels import interpret_mode
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "block_r"))
@@ -33,9 +30,8 @@ def bitunpack_tokens(words: jax.Array, *, bits: int,
     if b != bits or G % 4:
         raise ValueError(f"bad packed shape {words.shape}")
     rows = words.reshape(B * G // 4, 4, bits)
-    out = _bu.bitunpack(rows, bits=bits,
-                        block_r=min(block_r, rows.shape[0]),
-                        interpret=_interpret())
+    out = _bu.bitunpack(rows, bits=bits, block_r=block_r,
+                        interpret=interpret_mode())
     return out.reshape(B, G * 32)
 
 
@@ -58,7 +54,7 @@ def filter_aggregate(values: jax.Array, filter_col: jax.Array, cmp: str,
                              constant_values=pad_val)
     partials = _fa.filter_agg(values, filter_col, cmp, float(threshold),
                               block_rows=block_rows,
-                              interpret=_interpret())
+                              interpret=interpret_mode())
     return _fa.combine_partials(partials)
 
 
@@ -72,5 +68,5 @@ def masked_aggregate(values: jax.Array, mask: jax.Array, *,
         values = jnp.pad(values, (0, pad))
         mask = jnp.pad(mask.astype(jnp.int32), (0, pad))
     partials = _ba.block_agg(values, mask, block_rows=block_rows,
-                             interpret=_interpret())
+                             interpret=interpret_mode())
     return _fa.combine_partials(partials)

@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.configs.base import get_config
 from repro.core import make_store
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.archs import build_model
 from repro.serve.engine import Request, ServeEngine
 
@@ -28,6 +29,7 @@ def main() -> None:
     ap.add_argument("--max-seq", type=int, default=128)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.frontend != "none":

@@ -3,9 +3,7 @@
 Stands up the object store, ingests a synthetic corpus through the VOL,
 and runs the Trainer (object-store data path, packed ingest, checkpoint/
 restart).  ``--smoke`` selects the reduced config — the full configs are
-exercised via ``repro.launch.dryrun`` (this container has one CPU).
-On a real pod this same entry point runs under the production mesh with
-``--mesh single|multi``.
+exercised via ``repro.launch.dryrun``.
 """
 
 from __future__ import annotations
@@ -17,6 +15,7 @@ from repro.core import GlobalVOL, make_store
 from repro.core.partition import PartitionPolicy
 from repro.data.corpus import CorpusSpec, build_corpus
 from repro.data.pipeline import ObjectDataLoader
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.archs import build_model
 from repro.train.optimizer import OptConfig
 from repro.train.trainer import Trainer, TrainerConfig
@@ -37,6 +36,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--n-osds", type=int, default=8)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if not args.smoke:

@@ -25,7 +25,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -201,22 +200,22 @@ def moe_ffn(cfg: ArchConfig, p, x: jax.Array):
     if rules.strategy == "megatron_sp":
         dp = t["dp"]
         body = functools.partial(_megatron_body, cfg, fsdp_e, tp)
-        out, aux, zloss = shard_map(
+        out, aux, zloss = jax.shard_map(
             body, mesh=rules.mesh,
             in_specs=tuple([P(dp, tp, None)] + w_specs),
             out_specs=(P(dp, tp, None), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(x, p["router"], p["w1"], p["w3"], p["w2"], *shared)
         return out.astype(x.dtype), aux + zloss
 
     tok = rules.token_axes
     tok_spec = tok if len(tok) > 1 else tok[0]
     body = functools.partial(_token_body, cfg, fsdp_e, tp)
-    out, aux, zloss = shard_map(
+    out, aux, zloss = jax.shard_map(
         body, mesh=rules.mesh,
         in_specs=tuple([P(tok_spec, None)] + w_specs),
         out_specs=(P(tok_spec, None), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(x.reshape(B * S, D), p["router"], p["w1"], p["w3"], p["w2"],
       *shared)
     return out.reshape(B, S, D).astype(x.dtype), aux + zloss

@@ -457,3 +457,33 @@ def test_unpack_tokens_pallas_matches_reference():
                                    interpret=True))
     assert np.array_equal(ref, toks)
     assert np.array_equal(pal, toks)
+
+
+def test_auto_bitunpack_on_tpu_propagates_kernel_errors(monkeypatch):
+    """On a TPU backend "auto" decodes bitpack columns with the kernel
+    and a kernel error ends the scan: no warning, no numpy answer."""
+    import warnings
+
+    import jax
+    from repro.kernels import bitunpack as bu
+
+    def broken_kernel(words, bits, n, **_):
+        raise RuntimeError("kernel failed to lower")
+
+    store, vol, omap, table = make_world()
+    vol.write(omap, table)
+    assert {c["codec"] for c in fmt.block_header(
+        store.get(omap.extents[0].name))["columns"]} >= {"bitpack10"}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(bu, "bitunpack_words", broken_kernel)
+    fmt.set_bitunpack_backend("auto")
+    try:
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            with pytest.raises(RuntimeError, match="failed to lower"):
+                vol.scan("t").filter("y", "<", 500).agg("sum", "x") \
+                    .execute()
+            with pytest.raises(RuntimeError, match="failed to lower"):
+                fmt.decode_block(store.get(omap.extents[0].name))
+    finally:
+        fmt.set_bitunpack_backend("auto")

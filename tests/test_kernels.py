@@ -73,3 +73,32 @@ def test_kernel_matches_host_codec_end_to_end():
     out = ops.bitunpack_tokens(jnp.asarray(res["packed"]),
                                bits=int(res["bits"]))
     np.testing.assert_array_equal(np.asarray(out), toks[3:11])
+
+
+@pytest.mark.parametrize("rows", [1, 7, 8, 255, 256, 257, 1000, 4096, 6553])
+def test_pad_to_grid_blocks_fit_the_tpu_tiling(rows):
+    from repro.kernels.bitunpack import DEFAULT_BLOCK_R, pad_to_grid
+    bm, padded = pad_to_grid(rows)
+    n_blocks = padded // bm
+    assert padded % bm == 0 and padded >= rows and bm <= DEFAULT_BLOCK_R
+    if n_blocks > 1:
+        assert bm % 8 == 0                   # TPU sublane tile
+        assert padded - rows < 8 * n_blocks  # bounded padding
+    else:
+        assert padded == rows                # one block spans the array
+
+
+@pytest.mark.parametrize("bits", [1, 17, 24, 32])
+@pytest.mark.parametrize("rows", [257, 1000])
+def test_bitunpack_words_multi_block_bit_exact(bits, rows):
+    """Several grid steps, rebalanced block heights, and the 32-bit
+    codec whose groups fill their 32-lane segments with no zero planes."""
+    from repro.core.format import bitpack_decode
+    from repro.kernels.bitunpack import bitunpack_words
+    rng = np.random.default_rng(rows + bits)
+    n = rows * 128 - 5
+    v = rng.integers(0, 1 << bits, n, dtype=np.uint64).astype(np.uint32)
+    words = bitpack_encode(v, bits)
+    got = bitunpack_words(words, bits, n, interpret=True)
+    np.testing.assert_array_equal(got, bitpack_decode(words, bits, n))
+    np.testing.assert_array_equal(got, v)

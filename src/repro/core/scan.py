@@ -65,6 +65,7 @@ per-OSD combine plane (shared pipeline) instead of per-object gathers.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Any, Sequence
 
 import numpy as np
@@ -75,6 +76,7 @@ from repro.core import objclass as oc
 from repro.core.logical import (Dataspace, Hyperslab, RowRange,
                                 concat_tables)
 from repro.core.partition import objmap_key
+from repro.obs import span
 
 EXEC_OSD_COMBINE = "osd-combine"
 EXEC_SERVER_CONCAT = "server-concat"
@@ -85,6 +87,9 @@ EXEC_CLIENT_GATHER = "client-gather"
 
 PRUNE_STRATEGIES = ("auto", "pushdown", "client", "none")
 _AGG_FNS = ("sum", "count", "min", "max", "mean")
+
+# request identifiers of ``front.request`` spans, process-wide
+_REQ_IDS = itertools.count()
 
 
 # --------------------------------------------------------------------------
@@ -347,6 +352,13 @@ class ScanEngine:
 
     def _compile(self, omap, ops, *, allow_approx=False,
                  prune="auto", baseline=False, access=None) -> PhysicalPlan:
+        with span("front.compile"):
+            return self._compile_plan(omap, ops, allow_approx=allow_approx,
+                                      prune=prune, baseline=baseline,
+                                      access=access)
+
+    def _compile_plan(self, omap, ops, *, allow_approx, prune, baseline,
+                      access) -> PhysicalPlan:
         if prune not in PRUNE_STRATEGIES:
             raise ValueError(f"bad prune strategy {prune!r}; "
                              f"known: {PRUNE_STRATEGIES}")
@@ -483,43 +495,44 @@ class ScanEngine:
         whole chunks against its per-chunk zone-map xattrs before any
         cell moves; dropped chunks surface as ``fill`` in the assembled
         result.  Zero client zone-map requests either way."""
-        if prune not in PRUNE_STRATEGIES:
-            raise ValueError(f"bad prune strategy {prune!r}; "
-                             f"known: {PRUNE_STRATEGIES}")
-        if prune == "client":
-            raise ValueError(
-                "hyperslab plans prune per chunk ON the OSDs (per-chunk "
-                "zone maps are storage-side state); use prune="
-                "'auto'/'pushdown'/'none'")
-        space = amap.space
-        pred = ex.normalize(ex.ensure_pred(where)) \
-            if prune != "none" else None
-        targets = amap.lookup(hs)
-        names = [e.name for e, _ in targets]
-        by_osd: dict[str, list[int]] = {}
-        cluster = self.vol.store.cluster
-        for i, n in enumerate(names):
-            by_osd.setdefault(cluster.primary(n), []).append(i)
-        ops = (oc.op("hyperslab_slice", space=space.to_json(),
-                     sel=hs.to_json()),)
-        return PhysicalPlan(
-            dataset=space.name,
-            exec_cls=EXEC_SERVER_CONCAT,
-            prune="pushdown" if pred is not None else "none",
-            names=tuple(names),
-            ops=ops,
-            exec_ops=ops,
-            predicates=pred,
-            shards=tuple(sorted(
-                (osd, tuple(idxs)) for osd, idxs in by_osd.items())),
-            pushdown=True,
-            assemble="array",
-            access="fetch",
-            n_objects=amap.n_objects,
-            omap_version=getattr(amap, "version", -1),
-            array_meta={"space": space.to_json(), "sel": hs.to_json(),
-                        "squeeze": tuple(hs.squeeze), "fill": fill},
-        )
+        with span("front.compile"):
+            if prune not in PRUNE_STRATEGIES:
+                raise ValueError(f"bad prune strategy {prune!r}; "
+                                 f"known: {PRUNE_STRATEGIES}")
+            if prune == "client":
+                raise ValueError(
+                    "hyperslab plans prune per chunk ON the OSDs (per-"
+                    "chunk zone maps are storage-side state); use prune="
+                    "'auto'/'pushdown'/'none'")
+            space = amap.space
+            pred = ex.normalize(ex.ensure_pred(where)) \
+                if prune != "none" else None
+            targets = amap.lookup(hs)
+            names = [e.name for e, _ in targets]
+            by_osd: dict[str, list[int]] = {}
+            cluster = self.vol.store.cluster
+            for i, n in enumerate(names):
+                by_osd.setdefault(cluster.primary(n), []).append(i)
+            ops = (oc.op("hyperslab_slice", space=space.to_json(),
+                         sel=hs.to_json()),)
+            return PhysicalPlan(
+                dataset=space.name,
+                exec_cls=EXEC_SERVER_CONCAT,
+                prune="pushdown" if pred is not None else "none",
+                names=tuple(names),
+                ops=ops,
+                exec_ops=ops,
+                predicates=pred,
+                shards=tuple(sorted(
+                    (osd, tuple(idxs)) for osd, idxs in by_osd.items())),
+                pushdown=True,
+                assemble="array",
+                access="fetch",
+                n_objects=amap.n_objects,
+                omap_version=getattr(amap, "version", -1),
+                array_meta={"space": space.to_json(), "sel": hs.to_json(),
+                            "squeeze": tuple(hs.squeeze), "fill": fill},
+            )
 
     def compile_gather(self, names: Sequence[str],
                        pipelines: Sequence[Sequence[oc.ObjOp]],
@@ -591,6 +604,11 @@ class ScanEngine:
         ``omap`` is a currency hint for the row-slice targeting refresh:
         callers that just compiled against a map they hold pass it so a
         matching version skips the refresh probe entirely."""
+        with span("front.request", req=next(_REQ_IDS)):
+            return self._execute(plan, runner, before, omap)
+
+    def _execute(self, plan: PhysicalPlan, runner, before: dict | None,
+                 omap) -> tuple[Any, dict]:
         store = self.vol.store
         run = runner or self._direct
         if before is None:
@@ -612,11 +630,13 @@ class ScanEngine:
             # consume lazily: each OSD's partial folds in as it lands
             partials = list(partials_src)
             osd_pruned = list(pruned_src)
-            result = oc.combine_partials(ops, partials)
+            with span("front.assemble"):
+                result = oc.combine_partials(ops, partials)
             result_rows = 1
         elif plan.exec_cls == EXEC_PARTIAL_GATHER:
             raw = run("batch", names, pipes, None, shards)
-            result = oc.combine_partials(ops, raw)
+            with span("front.assemble"):
+                result = oc.combine_partials(ops, raw)
             result_rows = 1
         elif plan.exec_cls == EXEC_HOLISTIC_GATHER:
             col = ops[-1].params["col"]
@@ -624,26 +644,32 @@ class ScanEngine:
                                          shards)
             # frame-by-frame: decode each OSD's block on arrival, while
             # slower OSDs are still scanning
-            cols = [{col: fmt.decode_block(blob)[col].ravel()}
-                    for _, blob, _ in frames_src]
+            cols = []
+            for _, blob, _ in frames_src:
+                with span("front.assemble"):
+                    cols.append({col: fmt.decode_block(blob)[col].ravel()})
             osd_pruned = list(pruned_src)
-            result = oc.median_exact(cols, col)
+            with span("front.assemble"):
+                result = oc.median_exact(cols, col)
             result_rows = 1
         elif plan.exec_cls == EXEC_SERVER_CONCAT:
             frames_src, pruned_src = run("concat", names, pipes, preds,
                                          shards)
             parts: list = [None] * len(names)
             for frame in frames_src:  # decode overlaps slower OSDs
-                _place_frame(parts, frame)
+                with span("front.assemble"):
+                    _place_frame(parts, frame)
             osd_pruned = list(pruned_src)
             if plan.assemble == "parts":
                 result = parts
             elif plan.assemble == "array":
-                result = _assemble_array(plan, parts)
+                with span("front.assemble"):
+                    result = _assemble_array(plan, parts)
                 result_rows = int(result.size)
             else:
-                result = concat_tables(
-                    [p for p in parts if p is not None])
+                with span("front.assemble"):
+                    result = concat_tables(
+                        [p for p in parts if p is not None])
                 result_rows = oc.table_n_rows(result)
         elif plan.exec_cls == EXEC_TABLE_GATHER:
             result = run("batch", names, pipes, None, shards)

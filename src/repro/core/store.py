@@ -88,6 +88,7 @@ Self-healing plane (gray failures, not just fail-stop):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import itertools
 import json as _json
@@ -111,6 +112,7 @@ from repro.core.objclass import (
     required_columns, resolve_hyperslab, resolve_row_slice,
     run_pipeline, table_n_rows, zone_map_prunes)
 from repro.core.placement import ClusterMap
+from repro.obs import span
 
 # fixed cost modeled for one client<->OSD round trip (headers, framing,
 # dispatch) — what per-object fan-out pays N times and a batch pays once
@@ -651,7 +653,8 @@ class OSD:
             if got is not _MISS:
                 return got, 0
         self._pay_service(len(blob), meters)
-        table = decode_pipeline(blob, resolved)
+        with span("osd.decode"):
+            table = decode_pipeline(blob, resolved)
         if key is not None:
             ev, ins = self.cache.put(key, table, _result_nbytes(table))
             meters["cache_evictions"] += ev
@@ -718,7 +721,9 @@ class OSD:
         # miss: digest-verify THIS snapshot's blob, resolve any row
         # slice against the SAME snapshot's extent, then decode
         want = (xattr or {}).get("digest")
-        if want is not None and content_digest(blob) != int(want):
+        with span("osd.verify"):
+            bad = want is not None and content_digest(blob) != int(want)
+        if bad:
             self._quarantine_copy(name)
             return "corrupt", CorruptObject(
                 f"{name} on {self.osd_id}: stored bytes diverge from "
@@ -770,12 +775,14 @@ class OSD:
             # packed row-copy works on the raw blob — no decoded table
             # to share, so it bypasses the decode-level cache
             self._pay_service(len(blob), meters)
-            result = run_pipeline(blob, resolved, encode=encode)
+            with span("osd.decode"):
+                result = run_pipeline(blob, resolved, encode=encode)
             scanned = len(blob)
         else:
             table, scanned = self._decoded_table(
                 name, version, blob, resolved, meters)
-            result = apply_pipeline(table, resolved, encode=encode)
+            with span("osd.apply"):
+                result = apply_pipeline(table, resolved, encode=encode)
         if key is not None:
             meters["cache_misses"] += 1
             ev, ins = self.cache.put(key, result, _result_nbytes(result))
@@ -872,6 +879,11 @@ class OSD:
         monotonic version, so the bytes are provably identical), and
         reports 0 scanned bytes because no storage bytes were read.
         """
+        with span("osd.serve"):
+            return self._serve_batch(items, combine, concat, prune)
+
+    def _serve_batch(self, items, combine: bool, concat: bool,
+                     prune) -> Any:
         if combine and concat:
             raise ValueError("combine and concat are exclusive")
         self._touch()
@@ -950,7 +962,8 @@ class OSD:
                 tables.append(out)
                 served.append(k)
                 counts.append(table_n_rows(out))
-            frame = concat_encode(tables) if tables else None
+            with span("osd.encode"):
+                frame = concat_encode(tables) if tables else None
             return (frame, tuple(served), tuple(counts), scanned,
                     tuple(missing), tuple(pruned), tuple(corrupt),
                     meters)
@@ -975,7 +988,8 @@ class OSD:
                 continue
             partials.append(partial)
             scanned += nb
-        merged = merge_partials(ops, partials) if partials else None
+        with span("osd.encode"):
+            merged = merge_partials(ops, partials) if partials else None
         return (merged, len(partials), scanned, tuple(missing),
                 tuple(pruned), tuple(corrupt), meters)
 
@@ -1328,7 +1342,10 @@ class ObjectStore:
         float partial folds — bit-deterministic run to run.  Under
         ``stream=True`` each delivered item also counts in
         ``Fabric.stream_windows``.  A whole-request failure (OSD down)
-        retries every item of its group."""
+        retries every item of its group.  Each group's round trip and
+        its handling run in a ``store.request`` span (inline, the OSD's
+        serve nests in it), closed before any of its items is
+        yielded."""
         if completion_order is None:
             completion_order = stream
         tried: list[set[str]] = [set() for _ in names]
@@ -1339,25 +1356,28 @@ class ObjectStore:
             ordered = self._next_targets(pending, names, tried, last_err)
             pending = []
             if len(ordered) == 1 or not self.io_simulated():
-                completions = ((pair, run(*pair))
+                # each group runs when its response is taken
+                completions = ((pair, functools.partial(run, *pair))
                                for pair in ordered)
             else:
                 futs = {self._pool.submit(run, o, idxs): (o, idxs)
                         for o, idxs in ordered}
-                completions = ((futs[f], f.result())
+                completions = ((futs[f], f.result)
                                for f in (as_completed(futs)
                                          if completion_order else futs))
-            for (osd_id, idxs), (got, retries) in completions:
-                self._account_request()  # one round trip per OSD group
-                self.fabric.retries += retries
-                for i in idxs:
-                    tried[i].add(osd_id)
-                if isinstance(got, Exception):
+            for (osd_id, idxs), response in completions:
+                with span("store.request"):
+                    got, retries = response()
+                    self._account_request()  # one round trip per group
+                    self.fabric.retries += retries
                     for i in idxs:
-                        last_err[i] = got
-                    pending.extend(idxs)
-                    continue
-                retry, emitted = handle(idxs, got, last_err)
+                        tried[i].add(osd_id)
+                    if isinstance(got, Exception):
+                        for i in idxs:
+                            last_err[i] = got
+                        pending.extend(idxs)
+                        continue
+                    retry, emitted = handle(idxs, got, last_err)
                 pending.extend(retry)
                 for item in emitted:
                     if stream:

@@ -43,6 +43,7 @@ from repro.core import objclass as oc
 from repro.core.logical import RowRange
 from repro.core.partition import ObjectMap
 from repro.core.vol import GlobalVOL
+from repro.obs import span
 
 
 @dataclasses.dataclass
@@ -256,6 +257,9 @@ class ObjectDataLoader:
         return self._fetch_rows(self.rows_for_step(step))
 
     def _producer(self) -> None:
+        """Fill the queue from the loader's step on.  Building each batch
+        (not the wait for room in the queue) runs in a ``loader.produce``
+        span whose ``step`` matches the ``loader.wait`` that takes it."""
         step = self.state.step
         # hedged reads bypass the engine (per-object raw gets), so the
         # windowed streaming consume only applies without them
@@ -263,25 +267,31 @@ class ObjectDataLoader:
         while not self._stop.is_set():
             try:
                 if windowed:
-                    for _, batch in self._fetch_window(step):
+                    window = self._fetch_window(step)
+                    for _ in range(self.window_steps):
+                        with span("loader.produce", step=step):
+                            _, batch = next(window)
                         self._q.put(batch)
                         step += 1
                         if self._stop.is_set():
                             return
                 else:
-                    self._q.put(self.make_batch(step))
+                    with span("loader.produce", step=step):
+                        batch = self.make_batch(step)
+                    self._q.put(batch)
                     step += 1
             except Exception as e:  # surface in consumer
                 self._q.put(e)
                 return
 
     def __next__(self) -> dict[str, np.ndarray]:
-        if self._thread is None:
-            batch = self.make_batch(self.state.step)
-        else:
-            batch = self._q.get()
-            if isinstance(batch, Exception):
-                raise batch
+        with span("loader.wait", step=self.state.step):
+            if self._thread is None:
+                batch = self.make_batch(self.state.step)
+            else:
+                batch = self._q.get()
+                if isinstance(batch, Exception):
+                    raise batch
         self.state.step += 1
         return batch
 

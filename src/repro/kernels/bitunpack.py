@@ -31,6 +31,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import interpret_mode
+from repro.obs import span
 
 DEFAULT_BLOCK_R = 256
 
@@ -134,24 +135,26 @@ def bitunpack_words(words: np.ndarray, bits: int, n: int, *,
     selected jax backend (compiled on a TPU, interpreted elsewhere — see
     ``kernels.interpret_mode``), and slices the padding back off.
     Bit-exact with ``format.bitpack_decode`` — the zero pad groups decode
-    to zeros and are dropped.
+    to zeros and are dropped.  The whole adapter (pad, transfer, launch,
+    fetch) runs in a ``codec.bitunpack`` span.
     """
-    w = np.ascontiguousarray(words, dtype=np.uint32).reshape(-1, bits)
-    n_groups = w.shape[0]
-    if n_groups == 0:
-        return np.zeros((0,), np.uint32)[:n]
-    rows = -(-n_groups // 4)                    # 4 groups per 128-lane row
-    _, rows = pad_to_grid(rows)
-    if rows * 4 != n_groups:
-        padded = np.zeros((rows * 4, bits), np.uint32)
-        padded[:n_groups] = w
-        w = padded
-    if interpret is None:
-        interpret = interpret_mode()
-    with _stats_lock:
-        _stats["calls"] += 1
-        _stats["interpret_calls"] += int(interpret)
-        _launch_shapes.add((rows, bits))
-    vals = _bitunpack_jit(jnp.asarray(w.reshape(rows, 4, bits)), bits=bits,
-                          interpret=interpret)
-    return np.asarray(vals).view(np.uint32).ravel()[:n]
+    with span("codec.bitunpack"):
+        w = np.ascontiguousarray(words, dtype=np.uint32).reshape(-1, bits)
+        n_groups = w.shape[0]
+        if n_groups == 0:
+            return np.zeros((0,), np.uint32)[:n]
+        rows = -(-n_groups // 4)                # 4 groups per 128-lane row
+        _, rows = pad_to_grid(rows)
+        if rows * 4 != n_groups:
+            padded = np.zeros((rows * 4, bits), np.uint32)
+            padded[:n_groups] = w
+            w = padded
+        if interpret is None:
+            interpret = interpret_mode()
+        with _stats_lock:
+            _stats["calls"] += 1
+            _stats["interpret_calls"] += int(interpret)
+            _launch_shapes.add((rows, bits))
+        vals = _bitunpack_jit(jnp.asarray(w.reshape(rows, 4, bits)),
+                              bits=bits, interpret=interpret)
+        return np.asarray(vals).view(np.uint32).ravel()[:n]

@@ -159,38 +159,59 @@ def bitpack_decode(words: np.ndarray, bits: int, n: int) -> np.ndarray:
 
 # "auto": the Pallas kernel (kernels/bitunpack) decodes bitpack columns
 # whenever the jax backend is a TPU — the storage-side decode runs on the
-# accelerator that owns the shard, compiled, and any import, lowering or
-# runtime error of the kernel propagates out of the scan; on any other
-# backend the numpy butterfly codec is used.  "device" and "numpy" force
-# one side (tests force "device" to exercise the kernel in interpret
-# mode on CPU and assert bit-exactness).
+# accelerator that owns the shard, compiled, every bitpacked column of a
+# block in one round trip, and any import, lowering or runtime error of
+# the kernel propagates out of the scan; on any other backend the numpy
+# butterfly codec decodes them one column at a time.  "device" and
+# "numpy" force one side (tests force "device" to exercise the kernel in
+# interpret mode on CPU and assert bit-exactness).
 _BITUNPACK_MODE = "auto"
 _bitunpack_impl = None  # resolved lazily; None = not resolved yet
+# whether the resolved decoder takes a batch; set and reset with it, so
+# a decoder that stands in for ``_resolve_bitunpack`` takes one column
+_bitunpack_batched = False
 
 
 def set_bitunpack_backend(mode: str) -> None:
     """Select the bitpack-column decode backend: "auto" | "numpy" |
     "device" (see module comment).  Takes effect on the next decode."""
-    global _BITUNPACK_MODE, _bitunpack_impl
+    global _BITUNPACK_MODE, _bitunpack_impl, _bitunpack_batched
     if mode not in ("auto", "numpy", "device"):
         raise ValueError(f"unknown bitunpack backend {mode!r}")
     _BITUNPACK_MODE = mode
     _bitunpack_impl = None
+    _bitunpack_batched = False
 
 
 def _resolve_bitunpack():
-    global _bitunpack_impl
+    """The bitpack decoder of this process: the device's batched adapter
+    (``kernels.bitunpack.bitunpack_columns``: a list of ``(words, bits,
+    n)`` in, one round trip) or the numpy codec (``bitpack_decode``: one
+    column a call)."""
+    global _bitunpack_impl, _bitunpack_batched
     if _bitunpack_impl is None:
         want_device = _BITUNPACK_MODE == "device"
         if _BITUNPACK_MODE == "auto":
             import jax
             want_device = jax.default_backend() == "tpu"
         if want_device:
-            from repro.kernels.bitunpack import bitunpack_words
-            _bitunpack_impl = bitunpack_words
+            from repro.kernels.bitunpack import bitunpack_columns
+            _bitunpack_impl = bitunpack_columns
         else:
             _bitunpack_impl = bitpack_decode
+        _bitunpack_batched = want_device
     return _bitunpack_impl
+
+
+def _bitunpack_all(cols: list[tuple[np.ndarray, int, int]]
+                   ) -> list[np.ndarray]:
+    """Decode a block's bitpacked columns, each ``(words, bits, n)`` ->
+    (n,) uint32, with the resolved backend: one call for the whole
+    batch on the device, one call a column in numpy."""
+    impl = _resolve_bitunpack()
+    if _bitunpack_batched:
+        return impl(cols)
+    return [impl(*c) for c in cols]
 
 
 # --------------------------------------------------------------------------
@@ -214,23 +235,19 @@ def _encode_column(a: np.ndarray, codec: str) -> bytes:
 
 def _decode_column(buf, codec: str, dtype: str,
                    shape: tuple[int, ...]) -> np.ndarray:
-    """Decode one column buffer (bytes or memoryview).
+    """Decode one column buffer (bytes or memoryview) of codec ``none``
+    or ``zlib``; bitpack columns decode a block at a time
+    (:func:`decode_block`).
 
     Codec ``none`` is zero-copy: the returned (read-only) array aliases
     the block's buffer instead of materializing a private copy — the
     scan hot path never duplicates raw column bytes.
     """
-    n = int(np.prod(shape, dtype=np.int64)) if shape else 0
     if codec == "none":
         return np.frombuffer(buf, dtype=dtype).reshape(shape)
     if codec == "zlib":
         # decompress already yields a fresh buffer; alias it, no copy
         return np.frombuffer(zlib.decompress(buf), dtype=dtype).reshape(
-            shape)
-    if codec.startswith("bitpack"):
-        bits = int(codec[len("bitpack"):])
-        words = np.frombuffer(buf, dtype=np.uint32)
-        return _resolve_bitunpack()(words, bits, n).astype(dtype).reshape(
             shape)
     raise ValueError(f"unknown codec {codec!r}")
 
@@ -324,12 +341,25 @@ def decode_block(blob: bytes,
     out: dict[str, np.ndarray] = {}
     if header["layout"] == "col":
         view = memoryview(blob)  # zero-copy column slicing
+        packed = []  # bitpacked columns, decoded together below
         for c, blen in zip(header["columns"], header["lens"]):
             if columns is None or c["name"] in columns:
-                out[c["name"]] = _decode_column(
-                    view[off:off + blen], c["codec"], c["dtype"],
-                    tuple(c["shape"]))
+                if c["codec"].startswith("bitpack"):
+                    out[c["name"]] = None  # keeps the block's column order
+                    packed.append((c, (
+                        np.frombuffer(view[off:off + blen], dtype=np.uint32),
+                        int(c["codec"][len("bitpack"):]),
+                        int(np.prod(c["shape"], dtype=np.int64)))))
+                else:
+                    out[c["name"]] = _decode_column(
+                        view[off:off + blen], c["codec"], c["dtype"],
+                        tuple(c["shape"]))
             off += blen
+        if packed:
+            vals = _bitunpack_all([spec for _, spec in packed])
+            for (c, _), v in zip(packed, vals):
+                out[c["name"]] = v.astype(c["dtype"]).reshape(
+                    tuple(c["shape"]))
     else:
         fields = [(c["name"], c["dtype"],
                    tuple(c["shape"][1:]) or ()) for c in header["columns"]]

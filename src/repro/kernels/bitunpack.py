@@ -86,8 +86,18 @@ def bitunpack(words: jax.Array, *, bits: int,
     R = words.shape[0]
     if words.shape[1:] != (4, bits) or not 1 <= bits <= 32:
         raise ValueError(f"want (R, 4, {bits}), got {words.shape}")
-    bm, padded = pad_to_grid(R, block_r)
     w = jax.lax.bitcast_convert_type(words, jnp.int32).reshape(R, 4 * bits)
+    return _bitunpack_rows(w, bits=bits, block_r=block_r,
+                           interpret=interpret)
+
+
+def _bitunpack_rows(w: jax.Array, *, bits: int,
+                    block_r: int = DEFAULT_BLOCK_R,
+                    interpret: bool = False) -> jax.Array:
+    """The launch of :func:`bitunpack` on its (R, 4 * bits) int32 row
+    form, each row's 4 groups side by side -> (R, 128) int32."""
+    R = w.shape[0]
+    bm, padded = pad_to_grid(R, block_r)
     if padded != R:
         w = jnp.pad(w, ((0, padded - R), (0, 0)))
     out = pl.pallas_call(
@@ -102,59 +112,106 @@ def bitunpack(words: jax.Array, *, bits: int,
     return out[:R] if padded != R else out
 
 
-_bitunpack_jit = jax.jit(bitunpack,
-                         static_argnames=("bits", "block_r", "interpret"))
+def _unpack_batch(buf: jax.Array, *, layout: tuple[tuple[int, int], ...],
+                  interpret: bool) -> jax.Array:
+    """Columns' words laid back to back in one flat int32 ``buf`` ->
+    their values in one (rows, 128) int32 array, column after column:
+    one ``bitunpack`` launch per ``(rows, bits)`` entry of ``layout``,
+    each at the rows the host already padded to its grid."""
+    outs, off = [], 0
+    for rows, bits in layout:
+        size = rows * 4 * bits
+        # the barrier keeps XLA from turning slice-then-reshape into a
+        # relayout of the whole buffer to this column's row width
+        w = jax.lax.optimization_barrier(buf[off:off + size])
+        w = w.reshape(rows, 4 * bits)
+        outs.append(_bitunpack_rows(w, bits=bits, interpret=interpret))
+        off += size
+    return jnp.concatenate(outs)
 
-# decode counters of the host adapter: calls, calls run in interpret
-# mode, and the distinct (rows, bits) launches — on a TPU each new one
-# is a Mosaic compile
+
+_unpack_batch_jit = jax.jit(_unpack_batch,
+                            static_argnames=("layout", "interpret"))
+
+# decode counters of the host adapter: ``bitunpack`` launches, the
+# round trips that carry them, the launches run in interpret mode, the
+# distinct (rows, bits) launches, and the distinct batch layouts — on a
+# TPU each new layout is a compile of its program
 _stats_lock = threading.Lock()
-_stats = {"calls": 0, "interpret_calls": 0}
+_stats = {"calls": 0, "trips": 0, "interpret_calls": 0}
 _launch_shapes: set[tuple[int, int]] = set()
+_layouts: set[tuple[tuple[int, int], ...]] = set()
 
 
 def decode_stats() -> dict:
-    """Snapshot of the host adapter's counters (see ``bitunpack_words``)."""
+    """Snapshot of the host adapter's counters (see
+    ``bitunpack_columns``): ``calls / trips`` is the batch width."""
     with _stats_lock:
-        return dict(_stats, shapes=len(_launch_shapes))
+        return dict(_stats, shapes=len(_launch_shapes),
+                    layouts=len(_layouts))
 
 
 def reset_decode_stats() -> None:
     with _stats_lock:
-        _stats.update(calls=0, interpret_calls=0)
+        _stats.update(calls=0, trips=0, interpret_calls=0)
         _launch_shapes.clear()
+        _layouts.clear()
 
 
-def bitunpack_words(words: np.ndarray, bits: int, n: int, *,
-                    interpret: bool | None = None) -> np.ndarray:
-    """(G, bits) uint32 planar words -> (n,) uint32 via the Pallas kernel.
+def bitunpack_columns(cols: list[tuple[np.ndarray, int, int]], *,
+                      interpret: bool | None = None) -> list[np.ndarray]:
+    """Decode a batch of planar bitpacked columns in one device round
+    trip: each ``(words, bits, n)`` — (G, bits) uint32 words of n
+    values — comes back as an (n,) uint32 array.
 
-    Host-side adapter for the storage scan path
-    (``format._decode_column`` / ``objclass.run_pipeline``): pads the
-    group count up to a legal (R, 4, bits) grid, runs the kernel on the
-    selected jax backend (compiled on a TPU, interpreted elsewhere — see
-    ``kernels.interpret_mode``), and slices the padding back off.
-    Bit-exact with ``format.bitpack_decode`` — the zero pad groups decode
-    to zeros and are dropped.  The whole adapter (pad, transfer, launch,
-    fetch) runs in a ``codec.bitunpack`` span.
+    Host-side adapter for the storage scan path (``format.decode_block``
+    hands it every bitpacked column of a block it decodes): pads each
+    column's group count up to the (rows, 4, bits) grid ``pad_to_grid``
+    gives it, lays the padded words back to back in one host buffer,
+    moves that with one transfer, runs one program with one ``bitunpack``
+    launch per column (compiled on a TPU, interpreted elsewhere — see
+    ``kernels.interpret_mode``), fetches every value with one copy back
+    and cuts each column's padding off.  Bit-exact with
+    ``format.bitpack_decode`` — the zero pad groups decode to zeros and
+    are dropped.  A column with no values needs no launch; a batch of
+    them makes no trip.  The program is compiled once per ``layout``,
+    the tuple of its columns' (rows, bits): a scan that meets a new
+    projection or a new mix of per-object widths compiles once more
+    (``decode_stats()["layouts"]`` counts them).  The whole adapter
+    (pad, transfer, launch, fetch) runs in one ``codec.bitunpack``
+    span.
     """
     with span("codec.bitunpack"):
-        w = np.ascontiguousarray(words, dtype=np.uint32).reshape(-1, bits)
-        n_groups = w.shape[0]
-        if n_groups == 0:
-            return np.zeros((0,), np.uint32)[:n]
-        rows = -(-n_groups // 4)                # 4 groups per 128-lane row
-        _, rows = pad_to_grid(rows)
-        if rows * 4 != n_groups:
-            padded = np.zeros((rows * 4, bits), np.uint32)
-            padded[:n_groups] = w
-            w = padded
+        out = [np.zeros((0,), np.uint32) for _ in cols]
+        live, layout = [], []  # columns with values, their (rows, bits)
+        for i, (words, bits, _) in enumerate(cols):
+            groups = np.size(words) // bits
+            if groups:
+                live.append(i)
+                layout.append((pad_to_grid(-(-groups // 4))[1], bits))
+        if not live:
+            return out
+        layout = tuple(layout)
+        buf = np.zeros(sum(rows * 4 * bits for rows, bits in layout),
+                       np.uint32)
+        off = 0
+        for i, (rows, bits) in zip(live, layout):
+            w = np.asarray(cols[i][0], dtype=np.uint32).ravel()
+            buf[off:off + w.size] = w  # the rest of its rows: zero pad
+            off += rows * 4 * bits
         if interpret is None:
             interpret = interpret_mode()
         with _stats_lock:
-            _stats["calls"] += 1
-            _stats["interpret_calls"] += int(interpret)
-            _launch_shapes.add((rows, bits))
-        vals = _bitunpack_jit(jnp.asarray(w.reshape(rows, 4, bits)),
-                              bits=bits, interpret=interpret)
-        return np.asarray(vals).view(np.uint32).ravel()[:n]
+            _stats["calls"] += len(live)
+            _stats["trips"] += 1
+            _stats["interpret_calls"] += len(live) * int(interpret)
+            _launch_shapes.update(layout)
+            _layouts.add(layout)
+        vals = np.asarray(_unpack_batch_jit(
+            jnp.asarray(buf.view(np.int32)), layout=layout,
+            interpret=interpret)).view(np.uint32).ravel()
+        off = 0
+        for i, (rows, _) in zip(live, layout):
+            out[i] = vals[off:off + cols[i][2]]
+            off += rows * 128
+        return out

@@ -417,13 +417,13 @@ def test_checkpoint_save_writes_in_k_requests_per_leaf():
 def test_device_bitunpack_bit_exact_vs_numpy():
     jax = pytest.importorskip("jax")
     del jax
-    from repro.kernels.bitunpack import bitunpack_words
+    from repro.kernels.bitunpack import bitunpack_columns
     rng = np.random.default_rng(7)
     for bits in (1, 7, 13, 17):
         for n in (0, 1, 31, 32, 129, 1000, 4096):
             v = rng.integers(0, 1 << bits, n).astype(np.uint32)
             words = fmt.bitpack_encode(v, bits)
-            got = bitunpack_words(words, bits, n, interpret=True)
+            (got,) = bitunpack_columns([(words, bits, n)], interpret=True)
             assert np.array_equal(got, fmt.bitpack_decode(words, bits, n))
 
 
@@ -444,6 +444,41 @@ def test_run_pipeline_with_device_bitunpack_backend():
         fmt.set_bitunpack_backend("auto")
     assert float(got["sum"]) == float(expect["sum"])
     assert np.array_equal(dec["a"], table["a"])
+
+
+@pytest.mark.parametrize("columns", [None, ["k", "raw", "z", "q"], ["q"],
+                                     ["raw", "z"]])
+def test_device_decode_block_is_one_round_trip(columns):
+    """The device backend decodes every requested bitpacked column of a
+    block in one round trip, and equals the numpy backend, whole and
+    projected; ``none`` and ``zlib`` columns never reach the device."""
+    pytest.importorskip("jax")
+    from repro.kernels.bitunpack import decode_stats
+    rng = np.random.default_rng(17)
+    n = 3000
+    table = {"k": rng.integers(0, 1 << 20, n).astype(np.int32),
+             "raw": rng.normal(size=n),
+             "q": rng.integers(0, 50, n).astype(np.int32),
+             "z": rng.integers(-5, 5, n).astype(np.int64),
+             "d": rng.integers(0, 2500, n).astype(np.int32)}
+    blob = fmt.encode_block(table, codecs={"k": "bitpack20", "q": "bitpack6",
+                                           "z": "zlib", "d": "bitpack12"})
+    expect = fmt.decode_block(blob, columns)
+    fmt.set_bitunpack_backend("device")  # interpret-mode Pallas on CPU
+    try:
+        before = decode_stats()
+        got = fmt.decode_block(blob, columns)
+        after = decode_stats()
+    finally:
+        fmt.set_bitunpack_backend("auto")
+    packed = [c for c in (columns or table) if c in ("k", "q", "d")]
+    assert after["trips"] - before["trips"] == int(bool(packed))
+    assert after["calls"] - before["calls"] == len(packed)
+    assert list(got) == list(expect)
+    for c in got:
+        assert got[c].dtype == table[c].dtype
+        assert np.array_equal(got[c], expect[c])
+        assert np.array_equal(got[c], table[c])
 
 
 def test_unpack_tokens_pallas_matches_reference():
@@ -467,7 +502,7 @@ def test_auto_bitunpack_on_tpu_propagates_kernel_errors(monkeypatch):
     import jax
     from repro.kernels import bitunpack as bu
 
-    def broken_kernel(words, bits, n, **_):
+    def broken_kernel(cols, **_):
         raise RuntimeError("kernel failed to lower")
 
     store, vol, omap, table = make_world()
@@ -475,7 +510,7 @@ def test_auto_bitunpack_on_tpu_propagates_kernel_errors(monkeypatch):
     assert {c["codec"] for c in fmt.block_header(
         store.get(omap.extents[0].name))["columns"]} >= {"bitpack10"}
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(bu, "bitunpack_words", broken_kernel)
+    monkeypatch.setattr(bu, "bitunpack_columns", broken_kernel)
     fmt.set_bitunpack_backend("auto")
     try:
         with warnings.catch_warnings():

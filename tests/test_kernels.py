@@ -94,11 +94,70 @@ def test_bitunpack_words_multi_block_bit_exact(bits, rows):
     """Several grid steps, rebalanced block heights, and the 32-bit
     codec whose groups fill their 32-lane segments with no zero planes."""
     from repro.core.format import bitpack_decode
-    from repro.kernels.bitunpack import bitunpack_words
+    from repro.kernels.bitunpack import bitunpack_columns
     rng = np.random.default_rng(rows + bits)
     n = rows * 128 - 5
     v = rng.integers(0, 1 << bits, n, dtype=np.uint64).astype(np.uint32)
     words = bitpack_encode(v, bits)
-    got = bitunpack_words(words, bits, n, interpret=True)
+    (got,) = bitunpack_columns([(words, bits, n)], interpret=True)
     np.testing.assert_array_equal(got, bitpack_decode(words, bits, n))
     np.testing.assert_array_equal(got, v)
+
+
+# (bits, n) of each column of one batch; 60,416 and 20,031 rows are the
+# lineitem benchmark's full and last objects, at its 8 bitpacked widths
+_BATCHES = {
+    "mixed_widths": [(b, 4000 + 37 * b)
+                     for b in (1, 3, 6, 12, 14, 18, 23, 24, 32)],
+    "one_column": [(13, 1000)],
+    "zero_length_alone": [(7, 0)],
+    "zero_length_in_batch": [(5, 300), (9, 0), (17, 33)],
+    "lineitem_full_object": [(b, 60416)
+                             for b in (23, 18, 14, 3, 6, 12, 12, 12)],
+    "lineitem_last_object": [(b, 20031)
+                             for b in (23, 18, 14, 3, 6, 12, 12, 12)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BATCHES))
+def test_bitunpack_columns_batch_bit_exact(case):
+    """A batch decodes bit-exact with the numpy codec, column by column,
+    in one round trip that launches every non-empty column once."""
+    from repro.core.format import bitpack_decode
+    from repro.kernels.bitunpack import bitunpack_columns, decode_stats
+    rng = np.random.default_rng(len(case))
+    spec = _BATCHES[case]
+    cols = []
+    for bits, n in spec:
+        v = rng.integers(0, 1 << bits, n, dtype=np.uint64).astype(np.uint32)
+        cols.append((bitpack_encode(v, bits), bits, n))
+    before = decode_stats()
+    got = bitunpack_columns(cols, interpret=True)
+    after = decode_stats()
+    assert len(got) == len(cols)
+    for g, (words, bits, n) in zip(got, cols):
+        assert g.dtype == np.uint32 and g.shape == (n,)
+        np.testing.assert_array_equal(g, bitpack_decode(words, bits, n))
+    launched = sum(1 for _, n in spec if n)
+    assert after["calls"] - before["calls"] == launched
+    assert after["trips"] - before["trips"] == int(launched > 0)
+
+
+def test_bitunpack_columns_counts_one_layout_per_program():
+    """Each distinct (rows, bits) layout of a batch is one program, and
+    ``layouts`` counts them: a repeated layout adds none, another order
+    or another width of the same columns adds one."""
+    from repro.kernels.bitunpack import bitunpack_columns, decode_stats
+    rng = np.random.default_rng(3)
+
+    def col(bits, n=700):
+        v = rng.integers(0, 1 << bits, n, dtype=np.uint64).astype(np.uint32)
+        return bitpack_encode(v, bits), bits, n
+
+    a, b = col(5), col(11)
+    runs = [[a, b], [a, b], [b, a], [a, col(12)]]
+    seen = []
+    for cols in runs:
+        bitunpack_columns(cols, interpret=True)
+        seen.append(decode_stats()["layouts"])
+    assert [s - seen[0] for s in seen] == [0, 0, 1, 2]

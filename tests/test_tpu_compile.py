@@ -23,7 +23,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import block_agg, filter_agg
-from repro.kernels.bitunpack import _bitunpack_jit, pad_to_grid
+from repro.kernels.bitunpack import _unpack_batch, pad_to_grid
 
 # one 8 MiB float32 column: 2 Mi values
 COLUMN_8MIB = (8 << 20) // 4
@@ -52,19 +52,35 @@ def _compiled_text(fn, *specs) -> str:
     return jax.jit(fn).lower(*specs).compile().as_text()
 
 
+def _batch_spec(layout, sharding):
+    size = sum(rows * 4 * bits for rows, bits in layout)
+    return jax.ShapeDtypeStruct((size,), jnp.int32, sharding=sharding)
+
+
 @pytest.mark.parametrize("rows", [5376, 4096, 257, 1000])
 def test_bitunpack_compiles_at_scan_shapes(one_chip, rows):
     # 5376 and 4096 rows are the key-column launches of chip_smoke.py's
     # 2^25-row scan (688,128- and 524,288-row objects at the default
     # 8 MiB PartitionPolicy); 257 and 1000 need rebalanced multi-block
-    # grids.  The host adapter (bitunpack_words) pads rows with
+    # grids.  The host adapter (bitunpack_columns) pads rows with
     # pad_to_grid before the launch, so that padded shape is compiled.
-    _, padded = pad_to_grid(rows)
-    words = jax.ShapeDtypeStruct((padded, 4, 17), jnp.uint32,
-                                 sharding=one_chip)
+    layout = ((pad_to_grid(rows)[1], 17),)
     text = _compiled_text(
-        lambda w: _bitunpack_jit(w, bits=17, interpret=False), words)
+        lambda w: _unpack_batch(w, layout=layout, interpret=False),
+        _batch_spec(layout, one_chip))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n", [60416, 20031])
+def test_bitunpack_batch_compiles_at_lineitem_object(one_chip, n):
+    # the lineitem benchmark's full (60,416-row) and last (20,031-row)
+    # objects: 8 bitpacked columns in one program, one launch each
+    rows = pad_to_grid(-(-(-(-n // 32)) // 4))[1]
+    layout = tuple((rows, bits) for bits in (23, 18, 14, 3, 6, 12, 12, 12))
+    text = _compiled_text(
+        lambda w: _unpack_batch(w, layout=layout, interpret=False),
+        _batch_spec(layout, one_chip))
+    assert text.count('custom_call_target="tpu_custom_call"') == len(layout)
 
 
 def test_unpack_tokens_pallas_compiles_at_100m_batch(one_chip):

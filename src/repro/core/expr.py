@@ -31,6 +31,15 @@ Every comparison operator is defined ONCE, in :data:`CMP_TABLE`: a
 interval prune rule as required fields, so adding an operator without
 teaching every layer is a construction-time ``TypeError`` — not a
 silent never-prune on the client or a ``KeyError`` on the OSD.
+
+Aggregates take a second, smaller tree as their operand: a
+:class:`Value` is arithmetic over columns (:class:`Col`, :class:`Lit`,
+and :class:`Arith` for each operator of :data:`ARITH_TABLE`), so that
+``sum(l_extendedprice * l_discount)`` is evaluated on the OSD over the
+rows that pass the filter.  A value tree evaluates in float64
+(``evaluate``), lists its ``columns()`` for column pruning, travels as
+``to_json()``/``value_from_json()``, and is named by its canonical
+``text()`` (``l_extendedprice * l_discount``), which keys its partial.
 """
 
 from __future__ import annotations
@@ -94,6 +103,12 @@ def _sound(prune_fn, rng, *args) -> bool:
         return False
 
 
+def _n_rows(table: Mapping) -> int:
+    for v in table.values():
+        return int(np.asarray(v).shape[0])
+    return 0
+
+
 def _py(v):
     """JSON-able scalar (numpy scalars -> python)."""
     return v.item() if isinstance(v, np.generic) else v
@@ -141,11 +156,7 @@ class Const(Expr):
     value: bool
 
     def mask(self, table):
-        n = 0
-        for v in table.values():
-            n = int(np.asarray(v).shape[0])
-            break
-        return np.full(n, bool(self.value), dtype=bool)
+        return np.full(_n_rows(table), bool(self.value), dtype=bool)
 
     def prunes(self, zone_map):
         return not self.value
@@ -360,6 +371,135 @@ class Not(Expr):
 
 
 # --------------------------------------------------------------------------
+# value expressions: arithmetic over columns (aggregate operands)
+# --------------------------------------------------------------------------
+
+# each arithmetic operator, defined once: its vectorized evaluator
+ARITH_TABLE: dict[str, Callable[..., np.ndarray]] = {
+    "+": np.add, "-": np.subtract, "*": np.multiply}
+
+
+class Value:
+    """Base of the immutable arithmetic tree an aggregate takes as its
+    operand.  Subclasses implement ``_eval`` (float64, a literal stays
+    a scalar so it broadcasts against any column), ``columns``,
+    ``to_json`` and ``text``, the canonical text that names the
+    expression.  ``+``/``-``/``*`` compose trees, numbers becoming
+    literals: ``Col("price") * (1 - Col("discount"))``."""
+
+    def evaluate(self, table: Mapping[str, np.ndarray]) -> np.ndarray:
+        """The expression's float64 values over a column table; a
+        literal alone gives one value per row."""
+        out = self._eval(table)
+        return np.full(_n_rows(table), out) if np.ndim(out) == 0 else out
+
+    def _eval(self, table):
+        raise NotImplementedError
+
+    def columns(self) -> frozenset:
+        raise NotImplementedError
+
+    def to_json(self) -> dict:
+        raise NotImplementedError
+
+    def text(self) -> str:
+        raise NotImplementedError
+
+    def __add__(self, other) -> "Value":
+        return Arith("+", self, ensure_value(other))
+
+    def __radd__(self, other) -> "Value":
+        return Arith("+", ensure_value(other), self)
+
+    def __sub__(self, other) -> "Value":
+        return Arith("-", self, ensure_value(other))
+
+    def __rsub__(self, other) -> "Value":
+        return Arith("-", ensure_value(other), self)
+
+    def __mul__(self, other) -> "Value":
+        return Arith("*", self, ensure_value(other))
+
+    def __rmul__(self, other) -> "Value":
+        return Arith("*", ensure_value(other), self)
+
+
+@dataclasses.dataclass(frozen=True)
+class Col(Value):
+    """A column's values, as float64."""
+
+    col: str
+
+    def _eval(self, table):
+        return np.asarray(table[self.col], dtype=np.float64)
+
+    def columns(self):
+        return frozenset((self.col,))
+
+    def to_json(self):
+        return {"t": "col", "col": self.col}
+
+    def text(self):
+        return self.col
+
+
+@dataclasses.dataclass(frozen=True)
+class Lit(Value):
+    """A float64 constant."""
+
+    value: float
+
+    def __post_init__(self):
+        object.__setattr__(self, "value", float(self.value))
+
+    def _eval(self, table):
+        return np.float64(self.value)
+
+    def columns(self):
+        return frozenset()
+
+    def to_json(self):
+        return {"t": "lit", "value": self.value}
+
+    def text(self):
+        return repr(self.value)
+
+
+@dataclasses.dataclass(frozen=True)
+class Arith(Value):
+    """``left <op> right`` for one :data:`ARITH_TABLE` operator."""
+
+    op: str
+    left: Value
+    right: Value
+
+    def __post_init__(self):
+        if self.op not in ARITH_TABLE:
+            raise ValueError(f"bad arithmetic operator {self.op!r}; "
+                             f"known: {tuple(ARITH_TABLE)}")
+        for side in (self.left, self.right):
+            if not isinstance(side, Value):
+                raise TypeError(f"operand {side!r} is not a Value")
+
+    def _eval(self, table):
+        return ARITH_TABLE[self.op](self.left._eval(table),
+                                    self.right._eval(table))
+
+    def columns(self):
+        return self.left.columns() | self.right.columns()
+
+    def to_json(self):
+        return {"t": "arith", "op": self.op, "left": self.left.to_json(),
+                "right": self.right.to_json()}
+
+    def text(self):
+        # every nested operation in parentheses: one text per tree
+        def part(v: Value) -> str:
+            return f"({v.text()})" if isinstance(v, Arith) else v.text()
+        return f"{part(self.left)} {self.op} {part(self.right)}"
+
+
+# --------------------------------------------------------------------------
 # construction / normalization / wire form
 # --------------------------------------------------------------------------
 
@@ -384,6 +524,38 @@ def from_json(d: Mapping) -> Expr:
         raise ValueError(f"unknown expression node {d.get('t')!r}; "
                          f"known: {sorted(_FROM_JSON)}") from None
     return build(d)
+
+
+_VALUE_FROM_JSON: dict[str, Callable[[dict], Value]] = {
+    "col": lambda d: Col(d["col"]),
+    "lit": lambda d: Lit(d["value"]),
+    "arith": lambda d: Arith(d["op"], value_from_json(d["left"]),
+                             value_from_json(d["right"])),
+}
+
+
+def value_from_json(d: Mapping) -> Value:
+    """Rebuild a value expression from its wire form (``Value.to_json``)."""
+    try:
+        build = _VALUE_FROM_JSON[d["t"]]
+    except KeyError:
+        raise ValueError(f"unknown value node {d.get('t')!r}; "
+                         f"known: {sorted(_VALUE_FROM_JSON)}") from None
+    return build(d)
+
+
+def ensure_value(x) -> Value:
+    """Normalize one value spec: a :class:`Value`, its wire dict, or a
+    number (a literal)."""
+    if isinstance(x, Value):
+        return x
+    if isinstance(x, Mapping):
+        return value_from_json(x)
+    if isinstance(x, (int, float, np.integer, np.floating)) \
+            and not isinstance(x, bool):
+        return Lit(x)
+    raise TypeError(f"not a value: {x!r} (want a Value, its JSON form "
+                    "or a number)")
 
 
 def ensure(x) -> Expr:

@@ -24,6 +24,7 @@ import numpy as np
 from repro.core import expr as ex
 from repro.core import format as fmt
 from repro.core.logical import Dataspace, Hyperslab
+from repro.obs import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,10 +116,31 @@ def _filter(table, **params):
 
 
 # ---- decomposable aggregates: partial = dict of ndarrays ----
+#
+# An aggregate's operand (``col``) is a column name or a value
+# expression (``expr.Value`` or its wire dict): ``sum`` of
+# ``l_extendedprice * l_discount`` is evaluated here, on the OSD, over
+# the rows that reach the aggregate.
 
 
-def _agg_local(table, col: str, fn: str):
-    a = np.asarray(table[col], dtype=np.float64).ravel()
+def _is_expr(col) -> bool:
+    return not isinstance(col, str)
+
+
+def _operand_name(col) -> str:
+    """The name of an aggregate's operand: the column, or the
+    expression's canonical text."""
+    return ex.ensure_value(col).text() if _is_expr(col) else col
+
+
+def _operand_columns(col) -> frozenset:
+    return ex.ensure_value(col).columns() if _is_expr(col) \
+        else frozenset((col,))
+
+
+def _agg_local(table, col, fn: str):
+    a = np.asarray(ex.ensure_value(col).evaluate(table) if _is_expr(col)
+                   else table[col], dtype=np.float64).ravel()
     if fn == "count":
         return {"count": np.float64(a.size)}
     if a.size == 0:  # identity partials
@@ -174,12 +196,13 @@ def _agg_combine(partials: list, fn: str, **_):
 # ---- multi-aggregate: N decomposable aggregates as ONE mergeable tail ----
 
 
-def _magg_key(fn: str, col: str) -> str:
-    return f"{fn}({col})"
+def _magg_key(fn: str, col) -> str:
+    return f"{fn}({_operand_name(col)})"
 
 
 def _magg_local(table, specs):
-    """Partial = one agg partial per (fn, col) spec, keyed "fn(col)"."""
+    """Partial = one agg partial per (fn, operand) spec, keyed
+    "fn(name)" by the operand's name (``_operand_name``)."""
     return {_magg_key(fn, col): _agg_local(table, col, fn)
             for fn, col in specs}
 
@@ -541,7 +564,9 @@ def required_columns(ops: list[ObjOp]) -> list[str] | None:
     """Minimal column set a pipeline needs decoded, or None for "all".
 
     The whole pipeline is analyzed — not just a leading ``project`` — so
-    a filter→agg scan decodes only the filter and aggregate columns.
+    a filter→agg scan decodes only the filter and aggregate columns; an
+    aggregate over a value expression needs every column the expression
+    reads (``_operand_columns``).
     Returns None (decode everything) when the pipeline's *output* is the
     full table (table-out tail with no projection) or when it contains
     an op we cannot analyze (e.g. ``recompress``), which keeps results
@@ -562,10 +587,11 @@ def required_columns(ops: list[ObjOp]) -> list[str] | None:
             needed.update(_filter_expr(o.params).columns())
             continue
         if o.name in _SINGLE_COL_OPS:
-            needed.add(o.params["col"])
+            needed.update(_operand_columns(o.params["col"]))
             continue
         if o.name == "multi_agg":
-            needed.update(col for _, col in o.params["specs"])
+            for _, col in o.params["specs"]:
+                needed.update(_operand_columns(col))
             continue
         return None  # unknown/pass-through op: be conservative
     tail = get_impl(ops[-1].name)
@@ -583,18 +609,38 @@ def decode_pipeline(blob: bytes, ops: list[ObjOp]) -> dict:
     return fmt.decode_block(blob, columns=required_columns(ops))
 
 
+def _evaluates_expr(o: ObjOp) -> bool:
+    """Does this op evaluate a value expression (an aggregate over an
+    expression operand)?"""
+    if o.name == "agg":
+        return _is_expr(o.params["col"])
+    if o.name == "multi_agg":
+        return any(_is_expr(col) for _, col in o.params["specs"])
+    return False
+
+
 def apply_pipeline(table: dict, ops: list[ObjOp],
-                   encode: bool = True) -> Any:
+                   encode: bool = True, meters: dict | None = None) -> Any:
     """The post-decode half of :func:`run_pipeline`: run the op chain
     over an already-decoded column table.  Every built-in op builds a
     NEW dict (slices/masks/partials) and never mutates its input, so a
-    cached table can be replayed through any number of pipelines."""
+    cached table can be replayed through any number of pipelines.
+
+    An aggregate over a value expression runs under the ``osd.expr``
+    span, and ``meters["expr_rows"]`` (when given) counts the rows it
+    was evaluated over."""
     out: Any = table
     for o in ops:
         impl = get_impl(o.name)
         if not impl.table_in and not isinstance(out, dict):
             raise TypeError(f"{o.name}: pipeline type mismatch")
-        out = impl.local(out, **o.params)
+        if _evaluates_expr(o):
+            if meters is not None:
+                meters["expr_rows"] += table_n_rows(out)
+            with span("osd.expr"):
+                out = impl.local(out, **o.params)
+        else:
+            out = impl.local(out, **o.params)
         if not impl.table_out:
             return out  # partial; must be the last op
     return fmt.encode_block(out) if encode else out
@@ -626,11 +672,11 @@ def run_pipeline(blob: bytes, ops: list[ObjOp], encode: bool = True) -> Any:
 
 
 def _canon(v: Any) -> Any:
-    """Canonical JSON-able form of one op-param value: Exprs flatten to
-    their wire dicts, numpy scalars/arrays to plain lists, tuples to
-    lists — so a pipeline built from wire dicts and its normalized
-    (parsed-Expr) twin digest identically."""
-    if isinstance(v, ex.Expr):
+    """Canonical JSON-able form of one op-param value: predicate and
+    value expressions flatten to their wire dicts, numpy scalars/arrays
+    to plain lists, tuples to lists — so a pipeline built from wire
+    dicts and its normalized (parsed-Expr) twin digest identically."""
+    if isinstance(v, (ex.Expr, ex.Value)):
         return v.to_json()
     if isinstance(v, Mapping):
         return {str(k): _canon(x) for k, x in v.items()}

@@ -116,7 +116,7 @@ class Scan:
     dataset: str | None = None
     predicate: Any = None                   # expr.Expr | None (filter tree)
     projection: tuple[str, ...] | None = None
-    aggregates: tuple = ()                  # ((fn, col), ...)
+    aggregates: tuple = ()                  # ((fn, col | expr.Value), ...)
     median_col: str | None = None
     approx: bool = False
     row_range: tuple[int, int] | None = None
@@ -162,9 +162,19 @@ class Scan:
             raise ValueError("project needs at least one column")
         return dataclasses.replace(self, projection=tuple(cols))
 
-    def agg(self, fn: str, col: str) -> "Scan":
-        """Add an aggregate; N aggregates compile to ONE mergeable
-        ``multi_agg`` tail (still one partial per OSD)."""
+    def agg(self, fn: str, col) -> "Scan":
+        """Add an aggregate of a column, or of a value expression
+        evaluated on the OSDs over the rows that pass the filter::
+
+            scan.agg("sum", ex.Col("l_extendedprice") * ex.Col("l_discount"))
+
+        (an ``expr.Value`` or its wire dict; its result is keyed by the
+        expression's canonical text).  N aggregates compile to ONE
+        mergeable ``multi_agg`` tail (still one partial per OSD)."""
+        if not isinstance(col, str):
+            col = ex.ensure_value(col)
+            if fn == "median":
+                raise ValueError("median takes a column, not an expression")
         if fn == "median":
             return self.median(col)
         if fn not in _AGG_FNS:
@@ -221,9 +231,10 @@ class Scan:
             ops.append(oc.op("median", col=self.median_col))
         elif len(self.aggregates) == 1:
             fn, col = self.aggregates[0]
-            ops.append(oc.op("agg", col=col, fn=fn))
+            ops.append(oc.op("agg", col=_wire(col), fn=fn))
         elif self.aggregates:
-            ops.append(oc.op("multi_agg", specs=tuple(self.aggregates)))
+            ops.append(oc.op("multi_agg", specs=tuple(
+                (fn, _wire(col)) for fn, col in self.aggregates)))
         return ops
 
     def _bound(self, omap=None):
@@ -244,6 +255,12 @@ class Scan:
         return engine.execute(engine.compile(omap, self),
                               runner=self._runner, before=before,
                               omap=omap)
+
+
+def _wire(col):
+    """An aggregate operand as it ships: a column name, or a value
+    expression's wire dict."""
+    return col if isinstance(col, str) else col.to_json()
 
 
 def scan(dataset: str) -> Scan:

@@ -180,6 +180,8 @@ class Fabric:
     chunks_pruned: int = 0      # array chunks dropped OSD-side by
     #                             per-chunk zone maps before any cell
     #                             of the chunk was touched
+    expr_rows: int = 0          # rows OSDs evaluated an aggregate's
+    #                             value expression over (after filters)
     replica_lat_s: float = 0.0  # modeled replication write latency
     #                             (chain: per-hop, sequential; fan-out:
     #                             one hop, parallel)
@@ -211,6 +213,7 @@ class Fabric:
         self.cache_bytes = 0
         self.queue_wait_s = 0.0
         self.cache_neg_hits = self.chunks_pruned = 0
+        self.expr_rows = 0
         self.replica_lat_s = 0.0
         self.compactions = self.compaction_bytes = 0
         self.rebalance_bytes = 0
@@ -224,7 +227,7 @@ def _serve_meters() -> dict:
     issued the call — pool workers never touch fabric counters."""
     return {"cache_hits": 0, "cache_misses": 0, "cache_evictions": 0,
             "cache_bytes": 0, "queue_wait_s": 0.0,
-            "neg_hits": 0, "chunks_pruned": 0}
+            "neg_hits": 0, "chunks_pruned": 0, "expr_rows": 0}
 
 
 class OSDDown(RuntimeError):
@@ -782,7 +785,8 @@ class OSD:
             table, scanned = self._decoded_table(
                 name, version, blob, resolved, meters)
             with span("osd.apply"):
-                result = apply_pipeline(table, resolved, encode=encode)
+                result = apply_pipeline(table, resolved, encode=encode,
+                                        meters=meters)
         if key is not None:
             meters["cache_misses"] += 1
             ev, ins = self.cache.put(key, result, _result_nbytes(result))
@@ -1137,6 +1141,7 @@ class ObjectStore:
         f.queue_wait_s += m["queue_wait_s"]
         f.cache_neg_hits += m.get("neg_hits", 0)
         f.chunks_pruned += m.get("chunks_pruned", 0)
+        f.expr_rows += m.get("expr_rows", 0)
 
     def io_simulated(self) -> bool:
         """True when requests actually *wait* (NIC/disk bandwidth or OSD

@@ -8,6 +8,7 @@ import pytest
 
 from repro.core import (Column, GlobalVOL, LogicalDataset, PartitionPolicy,
                         Query, SkyhookDriver, make_store)
+from repro.core import expr as ex
 from repro.core import format as fmt
 from repro.core import objclass as oc
 from repro.core.store import OSDDown, PER_REQUEST_OVERHEAD_BYTES
@@ -168,15 +169,22 @@ def test_vol_write_ingest_costs_one_request_per_osd():
 
 # ------------------------------------------------------- per-OSD combine
 ALL_TAILS = [("agg", fn) for fn in ("sum", "count", "min", "max", "mean")]
+# the same tails over a value expression, as ``Scan.agg`` ships it
+X_TIMES_Y = (ex.Col("x") * (1 - ex.Col("y"))).to_json()
+COMBINE_CASES = [(t, fn, "x") for t, fn in ALL_TAILS] + \
+    [(t, fn, X_TIMES_Y) for t, fn in ALL_TAILS]
 
 
-@pytest.mark.parametrize("tail,fn", ALL_TAILS)
-def test_exec_combine_equals_client_side_combine(tail, fn):
+@pytest.mark.parametrize(
+    "tail,fn,col", COMBINE_CASES,
+    ids=[f"{t}-{fn}" + ("" if c == "x" else "-expr")
+         for t, fn, c in COMBINE_CASES])
+def test_exec_combine_equals_client_side_combine(tail, fn, col):
     store, vol, omap, table = make_world()
     vol.write(omap, table)
     names = omap.object_names()
     ops = [oc.op("filter", col="y", cmp="<", value=500),
-           oc.op(tail, col="x", fn=fn)]
+           oc.op(tail, col=col, fn=fn)]
     per_object = store.exec_batch(names, ops)
     merged = store.exec_combine(names, ops)
     # one partial per OSD, not per object
@@ -184,6 +192,13 @@ def test_exec_combine_equals_client_side_combine(tail, fn):
     assert len(merged) <= len(primaries) < len(per_object)
     assert oc.combine_partials(ops, merged) == pytest.approx(
         oc.combine_partials(ops, per_object), rel=1e-12)
+    # and both agree with numpy over the rows that pass
+    v = table["x"] if col == "x" else table["x"] * (1.0 - table["y"])
+    v = v[table["y"] < 500]
+    want = {"sum": v.sum(), "count": v.size, "min": v.min(),
+            "max": v.max(), "mean": v.mean()}[fn]
+    assert oc.combine_partials(ops, merged) == pytest.approx(want,
+                                                             rel=1e-12)
 
 
 def test_exec_combine_quantile_sketch_tail():

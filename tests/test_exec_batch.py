@@ -7,8 +7,10 @@ import pytest
 
 from repro.core import (Column, GlobalVOL, LogicalDataset, PartitionPolicy,
                         Query, RowRange, SkyhookDriver, make_store)
+from repro.core import expr as ex
 from repro.core import format as fmt
 from repro.core import objclass as oc
+from repro.core.scan import Scan
 from repro.core.store import PER_REQUEST_OVERHEAD_BYTES
 
 
@@ -219,6 +221,15 @@ def test_required_columns_minimal_sets():
     assert oc.required_columns([]) is None
     # non-analyzable ops decode everything
     assert oc.required_columns([oc.op("recompress", codecs={})]) is None
+    # an aggregate over a value expression needs every column it reads:
+    # TPC-H Q6 decodes its 4 columns of the 16
+    q6 = Scan(dataset="lineitem").filter_expr(ex.And((
+        ex.Cmp("l_shipdate", ">=", 366), ex.Cmp("l_shipdate", "<", 731),
+        ex.Between("l_discount", 0.05, 0.07),
+        ex.Cmp("l_quantity", "<", 24)))).agg(
+            "sum", ex.Col("l_extendedprice") * ex.Col("l_discount"))
+    assert oc.required_columns(q6.pipeline()) == [
+        "l_discount", "l_extendedprice", "l_quantity", "l_shipdate"]
 
 
 def test_pruned_pipeline_equals_full_decode():

@@ -334,20 +334,20 @@ def test_unresolved_row_slice_refuses_to_run():
     assert clamped[0].params["rows"] == (0, 0)
 
 
-def test_partial_gather_refuses_explicit_pushdown():
+def test_partial_gather_refuses_explicit_pushdown(monkeypatch):
     """Every BUILT-IN partial tail is mergeable now that row ranges
     ride the shared row_slice plane, so partial-gather only exists for
     extension ops whose tail has a combine but no associative merge.
     Register one: its positional responses carry no OSD prune info, so
     an EXPLICIT prune='pushdown' must refuse (not silently downgrade
     to the TOCTOU-prone client strategy), while 'auto' serves it via
-    the client planner."""
-    if "sum_nomerge" not in oc.registered_ops():
-        oc.register("sum_nomerge", oc.OpImpl(
-            lambda table, col: {"sum": np.asarray(
-                table[col], np.float64).sum()},
-            lambda parts, col: float(sum(p["sum"] for p in parts)),
-            decomposable=True, table_out=False))  # merge=None
+    the client planner.  The op is registered for this test only: the
+    registry pass of ``repro.analysis`` checks the real registry."""
+    monkeypatch.setitem(oc._REGISTRY, "sum_nomerge", oc.OpImpl(
+        lambda table, col: {"sum": np.asarray(
+            table[col], np.float64).sum()},
+        lambda parts, col: float(sum(p["sum"] for p in parts)),
+        decomposable=True, table_out=False))  # merge=None
     store, vol, omap, table = make_world()
     ops = [oc.op("filter", expr=ex.Cmp("y", "<", 500).to_json()),
            oc.op("sum_nomerge", col="x")]
@@ -415,6 +415,44 @@ def test_builder_expression_validation():
     chained = two.filter("x", ">", 0.0).isin("y", [1, 2])
     assert isinstance(chained.predicate, ex.And)
     assert len(chained.predicate.children) == 3  # flat conjunction
+
+
+# ------------------------------------------------- value expressions
+_XY = {"x": np.array([1.5, -2.0, 4.0]), "y": np.array([2, 3, 5], np.int32)}
+
+
+@pytest.mark.parametrize("tree,want,text,cols", [
+    (ex.Col("x"), [1.5, -2.0, 4.0], "x", {"x"}),
+    (ex.Lit(2), [2.0, 2.0, 2.0], "2.0", set()),
+    (ex.Col("x") * ex.Col("y"), [3.0, -6.0, 20.0], "x * y", {"x", "y"}),
+    (ex.Col("x") + 1, [2.5, -1.0, 5.0], "x + 1.0", {"x"}),
+    (1 - ex.Col("x"), [-0.5, 3.0, -3.0], "1.0 - x", {"x"}),
+    (ex.Col("x") * (1 - ex.Col("y")) * (1 + ex.Col("y")),
+     [-4.5, 16.0, -96.0], "(x * (1.0 - y)) * (1.0 + y)", {"x", "y"}),
+], ids=["col", "lit", "mul", "add", "rsub", "nested"])
+def test_value_expression_evaluates_names_and_roundtrips(tree, want, text,
+                                                         cols):
+    got = tree.evaluate(_XY)
+    assert got.dtype == np.float64 and got.shape == (3,)
+    np.testing.assert_array_equal(got, want)
+    assert tree.text() == text
+    assert tree.columns() == frozenset(cols)
+    back = ex.value_from_json(json.loads(json.dumps(tree.to_json())))
+    assert back == tree and back.text() == text
+    assert ex.ensure_value(tree.to_json()) == tree
+
+
+def test_value_expression_validation():
+    assert ex.ensure_value(np.int32(3)) == ex.Lit(3.0)
+    with pytest.raises(ValueError):
+        ex.Arith("/", ex.Col("x"), ex.Col("y"))
+    with pytest.raises(TypeError):
+        ex.Arith("*", ex.Col("x"), "y")
+    for bad in (True, "x"):
+        with pytest.raises(TypeError):
+            ex.ensure_value(bad)
+    with pytest.raises(ValueError):
+        ex.value_from_json({"t": "cmp", "col": "x", "cmp": "<", "value": 1})
 
 
 # ------------------------------------------------- soundness property

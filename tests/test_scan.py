@@ -397,6 +397,98 @@ def test_driver_executes_scans_directly():
     assert stats.exec_class == sc.EXEC_OSD_COMBINE
 
 
+# ------------------------------------------- expression operands
+PRICE_TIMES_DISC = ex.Col("x") * (1 - ex.Col("y"))
+
+
+@pytest.mark.parametrize("specs", [
+    (("sum", PRICE_TIMES_DISC),),
+    (("sum", PRICE_TIMES_DISC), ("count", "y"), ("max", PRICE_TIMES_DISC),
+     ("sum", "x")),
+], ids=["one", "mixed"])
+def test_expression_operand_merges_and_combines(specs):
+    """agg / multi_agg over a value expression: partials from the local
+    op fold through merge_partials and combine_partials like a
+    column's, keyed by the expression's canonical text."""
+    ops = [oc.op("filter", col="y", cmp="<", value=500),
+           oc.op("agg", col=specs[0][1].to_json(), fn=specs[0][0])
+           if len(specs) == 1 else
+           oc.op("multi_agg", specs=tuple((fn, c if isinstance(c, str)
+                                           else c.to_json())
+                                          for fn, c in specs))]
+    rng = np.random.default_rng(9)
+    tabs = [{"x": rng.normal(size=100),
+             "y": rng.integers(0, 1000, 100).astype(np.int32),
+             "z": rng.normal(size=100)} for _ in range(4)]
+    parts = [oc.apply_pipeline(t, ops) for t in tabs]
+    merged = oc.merge_partials(ops, parts[:2])
+    direct = oc.combine_partials(ops, parts)
+    via_merge = oc.combine_partials(ops, [merged, *parts[2:]])
+    assert direct == pytest.approx(via_merge, rel=1e-12)
+    m = np.concatenate([t["y"] < 500 for t in tabs])
+    x = np.concatenate([t["x"] for t in tabs])[m]
+    y = np.concatenate([t["y"] for t in tabs])[m]
+    want = {"sum(x * (1.0 - y))": (x * (1.0 - y)).sum(),
+            "max(x * (1.0 - y))": (x * (1.0 - y)).max(),
+            "count(y)": float(m.sum()), "sum(x)": x.sum()}
+    if len(specs) == 1:
+        assert direct == pytest.approx(want["sum(x * (1.0 - y))"], rel=1e-12)
+    else:
+        assert direct == pytest.approx(want, rel=1e-12)
+
+
+def test_pipeline_digest_tells_operands_apart_and_keeps_column_digests():
+    """Column operands digest as they always have (pinned), and two
+    different expression operands digest differently."""
+    price = Scan(dataset="lineitem").filter("l_extendedprice", ">", 95000.0)
+    for fn, col in (("count", "l_extendedprice"), ("sum", "l_extendedprice"),
+                    ("min", "l_extendedprice"), ("max", "l_extendedprice"),
+                    ("sum", "l_quantity")):
+        price = price.agg(fn, col)
+    one = Scan(dataset="t").filter("y", "<", 500).agg("mean", "x")
+    assert oc.pipeline_digest(price.pipeline()) == \
+        "8496e69c089bf7659fe34bfdddf19b893cd77bcd"
+    assert oc.pipeline_digest(one.pipeline()) == \
+        "ff1f51732742e2722177db505f895a6f14ef463e"
+    assert oc.pipeline_digest([oc.op("agg", col="x", fn="sum")]) == \
+        "900f877816b4882fd1b69d3d22d19cd2e3ad839c"
+    base = Scan(dataset="t").filter("y", "<", 500)
+    digests = {oc.pipeline_digest(base.agg("sum", e).pipeline())
+               for e in (ex.Col("x") * ex.Col("y"), ex.Col("y") * ex.Col("x"),
+                         ex.Col("x") + ex.Col("y"), ex.Col("x") * 2,
+                         ex.Col("x") * 3, "x")}
+    assert len(digests) == 6
+    # the wire form and its parsed twin digest alike
+    ops = base.agg("sum", ex.Col("x") * ex.Col("y")).pipeline()
+    parsed = [oc.ObjOp(o.name, dict(o.params, col=ex.value_from_json(
+        o.params["col"]))) if o.name == "agg" else o for o in ops]
+    assert oc.pipeline_digest(parsed) == oc.pipeline_digest(ops)
+
+
+def test_expression_aggregate_scan_runs_on_the_osd_combine_plane():
+    store, vol, omap, table = make_world()
+    expr = ex.Col("x") * (1 - ex.Col("y"))
+    s = vol.scan("t").filter_expr(ex.Between("y", 100, 400)).agg("sum", expr)
+    plan = s.explain()
+    assert plan.exec_cls == sc.EXEC_OSD_COMBINE
+    assert [o.name for o in plan.exec_ops] == ["filter", "agg"]
+    res, stats = s.execute()
+    m = (table["y"] >= 100) & (table["y"] <= 400)
+    assert res == pytest.approx(
+        (table["x"][m] * (1.0 - table["y"][m])).sum(), rel=1e-12)
+    # evaluated on the OSDs over the rows that pass, one partial each
+    assert stats["expr_rows"] == int(m.sum())
+    primaries = {store.cluster.primary(n) for n in omap.object_names()}
+    assert stats["ops"] == len(primaries)
+    # several aggregates keep the expression's name as their key
+    res2, _ = vol.scan("t").filter_expr(ex.Between("y", 100, 400)) \
+        .agg("sum", expr).agg("count", "x").execute()
+    assert res2["sum(x * (1.0 - y))"] == pytest.approx(res, rel=1e-12)
+    assert res2["count(x)"] == float(m.sum())
+    with pytest.raises(ValueError, match="median"):
+        vol.scan("t").agg("median", expr)
+
+
 # ----------------------------------------------------- multi_agg op
 def test_multi_agg_column_pruning_and_merge():
     specs = (("sum", "x"), ("count", "y"))

@@ -71,12 +71,20 @@ def test_bitunpack_compiles_at_scan_shapes(one_chip, rows):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("n", [60416, 20031])
-def test_bitunpack_batch_compiles_at_lineitem_object(one_chip, n):
+LINEITEM_BITS = (23, 18, 14, 3, 6, 12, 12, 12)
+Q6_BITS = (6, 12)  # l_quantity, l_shipdate
+
+
+@pytest.mark.parametrize(
+    "n,widths", [(60416, LINEITEM_BITS), (20031, LINEITEM_BITS),
+                 (60416, Q6_BITS), (20031, Q6_BITS)],
+    ids=["60416", "20031", "60416-q6", "20031-q6"])
+def test_bitunpack_batch_compiles_at_lineitem_object(one_chip, n, widths):
     # the lineitem benchmark's full (60,416-row) and last (20,031-row)
-    # objects: 8 bitpacked columns in one program, one launch each
+    # objects: their 8 bitpacked columns (select, range) or Q6's 2, in
+    # one program, one launch each
     rows = pad_to_grid(-(-(-(-n // 32)) // 4))[1]
-    layout = tuple((rows, bits) for bits in (23, 18, 14, 3, 6, 12, 12, 12))
+    layout = tuple((rows, bits) for bits in widths)
     text = _compiled_text(
         lambda w: _unpack_batch(w, layout=layout, interpret=False),
         _batch_spec(layout, one_chip))

@@ -21,8 +21,9 @@ RPC inside a body holding any discovered ``threading.Lock``.
 
 **(d) write-path** — every function that rewrites OSD blob/xattr state
 must reach cache invalidation in its call closure, and every user of
-``_next_version`` must reach both ``content_digest`` stamping and
-invalidation (the version/digest/cache triple moves together).
+``_next_version`` must reach both digest stamping (``content_digest`` or
+``stamp_digests``) and invalidation (the version/digest/cache triple
+moves together).
 
 The call graph is intentionally an under-approximation: calls on
 receivers whose type cannot be resolved from ``VAR_TYPES``/``self``
@@ -82,7 +83,7 @@ OSD_RPCS = frozenset({"get", "put", "put_batch", "exec_cls",
 # pass (d): blob/xattr stores and the invalidation/stamping calls
 OSD_STATE_ATTRS = frozenset({"data", "xattrs"})
 INVALIDATORS = frozenset({"invalidate", "invalidate_cached"})
-DIGEST_FNS = frozenset({"content_digest"})
+DIGEST_FNS = frozenset({"content_digest", "stamp_digests"})
 
 _SCOPE_NODES = (ast.FunctionDef, ast.AsyncFunctionDef, ast.Lambda,
                 ast.ClassDef)

@@ -8,10 +8,10 @@ monotonic ``version`` stamped by every write path gives exact
 invalidation for free: a write, heal, or compaction bumps the version,
 so stale entries simply never match a current lookup — eviction is a
 memory concern, never a correctness one.  Entries are derived from
-digest-verified blobs at insert time and are dropped eagerly on
-anything that pulls the source copy out of service (rewrite,
-quarantine, delete), so a cached result is never served across a
-version bump.
+bytes digest-verified on the read that inserts them and are dropped
+eagerly on anything that pulls the source copy out of service
+(rewrite, quarantine, delete), so a cached result is never served
+across a version bump.
 
 Thread-safety: all mutators run under one internal lock (OSD serve
 paths run concurrently on the store's pool workers).  The cache never

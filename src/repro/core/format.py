@@ -26,6 +26,7 @@ Layout transformation (row<->col) is lossless and is the mechanism behind
 
 from __future__ import annotations
 
+import functools
 import json
 import struct
 import zlib
@@ -41,21 +42,136 @@ _VERSION = 2
 # crc32c when the (optional) C extension is around, zlib's crc32
 # otherwise — both run at C speed over the encoded blob; the store only
 # needs A content digest that is cheap enough to verify on every read,
-# not a specific polynomial
+# not a specific polynomial.  ``_CRC_POLY`` is the chosen crc's
+# reflected polynomial, which :func:`_crc_combine` needs.
 try:  # pragma: no cover - environment-dependent
     from crc32c import crc32c as _crc
+    _CRC_POLY = 0x82F63B78
 except Exception:  # pragma: no cover
     _crc = zlib.crc32
+    _CRC_POLY = 0xEDB88320
 
 
 def content_digest(blob: bytes) -> int:
     """Content digest of an encoded object blob (crc32c when available,
     crc32 otherwise).  Stamped into every object's xattrs at write time
-    (``ObjectStore.put`` / ``put_batch`` / each replication hop) so any
-    copy is independently verifiable: reads, ``scrub()`` and
-    digest-verified ``recover()`` all check stored bytes against this
-    value before serving or propagating them."""
+    (``ObjectStore.put`` / ``put_batch`` / each replication hop, through
+    :func:`stamp_digests`) so any copy is independently verifiable.
+    Every read verifies every byte it decodes (this digest, or the
+    extent digests of a projected read, :func:`check_extents`); scrub,
+    ``get()`` and digest-verified ``recover()`` verify every byte
+    against this value before serving or propagating it."""
     return _crc(bytes(blob)) & 0xFFFFFFFF
+
+
+def _extent_digest(view: memoryview) -> int:
+    """:func:`content_digest` of one extent, read in place."""
+    return _crc(view) & 0xFFFFFFFF
+
+
+def _gf2_apply(op: tuple[int, ...], v: int) -> int:
+    """A 32x32 GF(2) matrix (``op[i]`` the image of bit i) times ``v``."""
+    out = 0
+    for row in op:
+        if not v:
+            break
+        if v & 1:
+            out ^= row
+        v >>= 1
+    return out
+
+
+@functools.cache
+def _zeros_op(k: int) -> tuple[int, ...]:
+    """The operator that advances a crc over 2**k zero bytes."""
+    if k:
+        prev = _zeros_op(k - 1)
+        return tuple(_gf2_apply(prev, row) for row in prev)
+    op = (_CRC_POLY, *(1 << i for i in range(31)))  # one zero bit
+    for _ in range(3):  # squared thrice: eight bits
+        op = tuple(_gf2_apply(op, row) for row in op)
+    return op
+
+
+def _crc_combine(crc1: int, crc2: int, len2: int) -> int:
+    """The crc of ``a + b`` from ``crc(a)``, ``crc(b)`` and ``len(b)``
+    (zlib's ``crc32_combine``), so the whole-object digest folds out of
+    the extent digests without a second pass over the blob."""
+    k = 0
+    while len2:
+        if len2 & 1:
+            crc1 = _gf2_apply(_zeros_op(k), crc1)
+        len2 >>= 1
+        k += 1
+    return crc1 ^ crc2
+
+
+def _col_extents(blob: bytes
+                 ) -> tuple[dict, list[tuple[int, int]]] | None:
+    """A column-layout block's header and the ``(start, stop)`` of its
+    header extent (magic, length and header JSON) and of each column
+    buffer in header order, tiling the blob; None for a row-layout
+    block or bytes that are not a block."""
+    try:
+        header = block_header(blob)
+        if header["layout"] != "col":
+            return None
+        (hlen,) = struct.unpack("<I", blob[4:8])
+        bounds = [0, 8 + hlen]
+        for n in header["lens"]:
+            bounds.append(bounds[-1] + int(n))
+    except (ValueError, KeyError, TypeError, struct.error):
+        return None
+    if bounds[-1] != len(blob):
+        return None
+    return header, list(zip(bounds, bounds[1:]))
+
+
+def stamp_digests(blob: bytes) -> dict:
+    """The digests a write stamps into an object's xattrs: ``digest``
+    (:func:`content_digest` of the whole blob) and, for a column-layout
+    block, ``extent_digests``: one for the header extent, then one per
+    column buffer in header order — what a projected read checks
+    (:func:`check_extents`).  A row-layout block or bytes that are not
+    a block get ``digest`` alone.  One pass over the blob: a block's
+    ``digest`` is combined from its extent digests."""
+    got = _col_extents(blob)
+    if got is None:
+        return {"digest": content_digest(blob)}
+    view = memoryview(blob)
+    ext = [_extent_digest(view[s:e]) for s, e in got[1]]
+    whole = ext[0]
+    for (s, e), d in zip(got[1][1:], ext[1:]):
+        whole = _crc_combine(whole, d, e - s)
+    return {"digest": whole, "extent_digests": ext}
+
+
+def check_extents(blob: bytes, extent_digests: list[int],
+                  columns: list[str]) -> tuple[bool, int]:
+    """Check the extents a decode of ``columns`` reads against their
+    stamped digests: the header extent first, and only then, at the
+    offsets that header gives, each named column's buffer.  Returns
+    ``(ok, bytes_checked)``; a header that fails its digest, or stamps
+    that do not match its extents, are not ok."""
+    view = memoryview(blob)
+    if len(view) < 8:
+        return False, 0
+    (hlen,) = struct.unpack("<I", view[4:8])
+    checked = min(8 + hlen, len(view))
+    if _extent_digest(view[:checked]) != extent_digests[0]:
+        return False, checked
+    got = _col_extents(blob)
+    if got is None or len(got[1]) != len(extent_digests):
+        return False, checked
+    header, extents = got
+    want = set(columns)
+    for c, (s, e), d in zip(header["columns"], extents[1:],
+                            extent_digests[1:]):
+        if c["name"] in want:
+            checked += e - s
+            if _extent_digest(view[s:e]) != d:
+                return False, checked
+    return True, checked
 
 
 # --------------------------------------------------------------------------

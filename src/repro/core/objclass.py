@@ -600,15 +600,6 @@ def required_columns(ops: list[ObjOp]) -> list[str] | None:
     return sorted(needed)
 
 
-def decode_pipeline(blob: bytes, ops: list[ObjOp]) -> dict:
-    """The decode half of :func:`run_pipeline`: the minimal column
-    table the pipeline needs, straight from the block.  Split out so
-    the OSD result cache can keep decoded column sets around and feed
-    them back through :func:`apply_pipeline` without touching the blob
-    again (the decode is the service cost the cache elides)."""
-    return fmt.decode_block(blob, columns=required_columns(ops))
-
-
 def _evaluates_expr(o: ObjOp) -> bool:
     """Does this op evaluate a value expression (an aggregate over an
     expression operand)?"""
@@ -668,7 +659,8 @@ def run_pipeline(blob: bytes, ops: list[ObjOp], encode: bool = True) -> Any:
         if len(ops) != 1:
             raise ValueError("select_packed must be the only op")
         return select_packed(blob, **ops[0].params)
-    return apply_pipeline(decode_pipeline(blob, ops), ops, encode=encode)
+    table = fmt.decode_block(blob, columns=required_columns(ops))
+    return apply_pipeline(table, ops, encode=encode)
 
 
 def _canon(v: Any) -> Any:

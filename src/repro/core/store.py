@@ -62,16 +62,23 @@ Self-healing plane (gray failures, not just fail-stop):
 
   * every write path (``put``, ``put_batch`` windows, each replication
     hop) stamps a content ``digest`` (``format.content_digest`` over the
-    encoded blob) into the object's xattrs, so EVERY copy is
-    independently verifiable;
-  * every read verifies the served copy against its own digest; a
-    divergent copy is quarantined on its OSD (``OSD.quarantine``) and
-    surfaced as :class:`CorruptObject`, which the batched planes treat
-    exactly like a missing replica — per-object failover to the next
-    copy in the acting set (``Fabric.corruptions_detected`` counts the
-    catches);
-  * ``scrub()`` is the background maintenance pass: a per-OSD walker
-    verifies every local copy, quarantines divergent/torn ones, and
+    encoded blob) into the object's xattrs, and for a column-layout
+    block one ``extent_digests`` entry for its header and each column
+    buffer (``format.stamp_digests``), so EVERY copy is independently
+    verifiable;
+  * every read verifies every byte it decodes: a projected pipeline
+    (``required_columns`` names a subset) checks the header extent and
+    its columns' extents, any other read the whole-object digest
+    (``Fabric.verify_bytes`` counts the bytes checked).  A divergent
+    copy is quarantined on its OSD (``OSD.quarantine``) and surfaced as
+    :class:`CorruptObject`, which the batched planes treat exactly like
+    a missing replica — per-object failover to the next copy in the
+    acting set (``Fabric.corruptions_detected`` counts the catches).
+    Rot in a column no read decodes stays in service until scrub, or a
+    read that decodes it, catches it;
+  * ``scrub()`` verifies every byte: it is the background maintenance
+    pass, a per-OSD walker that checks every local copy against its
+    whole-object digest, quarantines divergent/torn ones, and
     heals from the highest-version digest-verified copy through the
     replication chain; ``recover()`` is digest-verified too — it
     refuses a corrupt source, falls down the surviving copies, and
@@ -103,12 +110,13 @@ import numpy as np
 
 from repro.core import expr as ex
 from repro.core.cache import Negative as _Negative, ResultCache, _MISS
-from repro.core.format import content_digest
+from repro.core.format import (
+    check_extents, content_digest, decode_block, stamp_digests,
+)
 from repro.core.objclass import (
     ObjOp, apply_pipeline, compact_merge as _compact_merge_blocks,
-    concat_encode, decode_pipeline,
-    get_impl as _impl, has_hyperslab, has_row_slice, merge_partials,
-    normalize_exprs, pipeline_digest, pipeline_mergeable,
+    concat_encode, get_impl as _impl, has_hyperslab, has_row_slice,
+    merge_partials, normalize_exprs, pipeline_digest, pipeline_mergeable,
     required_columns, resolve_hyperslab, resolve_row_slice,
     run_pipeline, table_n_rows, zone_map_prunes)
 from repro.core.placement import ClusterMap
@@ -182,6 +190,9 @@ class Fabric:
     #                             of the chunk was touched
     expr_rows: int = 0          # rows OSDs evaluated an aggregate's
     #                             value expression over (after filters)
+    verify_bytes: int = 0       # bytes digest-checked by served reads
+    #                             (the extents a projected read decodes,
+    #                             the whole blob otherwise)
     replica_lat_s: float = 0.0  # modeled replication write latency
     #                             (chain: per-hop, sequential; fan-out:
     #                             one hop, parallel)
@@ -213,11 +224,22 @@ class Fabric:
         self.cache_bytes = 0
         self.queue_wait_s = 0.0
         self.cache_neg_hits = self.chunks_pruned = 0
-        self.expr_rows = 0
+        self.expr_rows = self.verify_bytes = 0
         self.replica_lat_s = 0.0
         self.compactions = self.compaction_bytes = 0
         self.rebalance_bytes = 0
         self.gc_objects = self.gc_bytes = 0
+
+
+def _stamp(blob: bytes, xattr: dict | None = None, **fields) -> dict:
+    """The xattr a write lands with: the caller's ``xattr``, ``fields``
+    (a fresh ``version``, a zone map), and the blob's digests
+    (``format.stamp_digests``) — so no write stamps one digest without
+    the other, and extent digests carried over from another blob never
+    survive."""
+    kept = {k: v for k, v in (xattr or {}).items()
+            if k != "extent_digests"}
+    return {**kept, **fields, **stamp_digests(blob)}
 
 
 def _serve_meters() -> dict:
@@ -227,7 +249,8 @@ def _serve_meters() -> dict:
     issued the call — pool workers never touch fabric counters."""
     return {"cache_hits": 0, "cache_misses": 0, "cache_evictions": 0,
             "cache_bytes": 0, "queue_wait_s": 0.0,
-            "neg_hits": 0, "chunks_pruned": 0, "expr_rows": 0}
+            "neg_hits": 0, "chunks_pruned": 0, "expr_rows": 0,
+            "verify_bytes": 0}
 
 
 class OSDDown(RuntimeError):
@@ -581,9 +604,7 @@ class OSD:
         re-reading it."""
         self._touch()
         blob, zm = _compact_merge_blocks(list(blobs))
-        stamped = dict(xattr or {})
-        stamped["zone_map"] = zm
-        stamped["digest"] = content_digest(blob)
+        stamped = _stamp(blob, xattr, zone_map=zm)
         with self.lock:
             if self.disk_bw:
                 time.sleep(len(blob) / self.disk_bw)  # serial disk
@@ -641,15 +662,15 @@ class OSD:
             time.sleep(nbytes / self.scan_bw)
 
     def _decoded_table(self, name: str, version, blob: bytes,
-                       resolved: list[ObjOp],
+                       cols: list[str] | None,
                        meters: dict) -> tuple[dict, int]:
-        """The decoded column table a pipeline needs, through the
-        decode-level cache (shared across pipelines that read the same
-        columns).  Returns ``(table, scanned_bytes)`` — 0 scanned when
-        the decode was elided (no storage bytes were read)."""
+        """The decoded column table of ``cols`` (None: every column),
+        through the decode-level cache (shared across pipelines that
+        read the same columns).  Returns ``(table, scanned_bytes)`` — 0
+        scanned when the decode was elided (no storage bytes were
+        read)."""
         key = None
         if self.cache.capacity > 0 and version is not None:
-            cols = required_columns(resolved)
             key = (name, int(version), "cols",
                    tuple(cols) if cols is not None else None)
             got = self.cache.get(key)
@@ -657,7 +678,7 @@ class OSD:
                 return got, 0
         self._pay_service(len(blob), meters)
         with span("osd.decode"):
-            table = decode_pipeline(blob, resolved)
+            table = decode_block(blob, columns=cols)
         if key is not None:
             ev, ins = self.cache.put(key, table, _result_nbytes(table))
             meters["cache_evictions"] += ev
@@ -681,8 +702,8 @@ class OSD:
         pipeline digest can map to different payloads).  Cached entries
         are keyed by the snapshot's monotonic version: any write, heal,
         or compaction bumps it, so an entry can never be served across
-        a version bump — and every entry was derived from a
-        digest-verified blob at insert time.
+        a version bump — and every entry was derived from bytes that
+        were digest-verified on the read that inserted it.
 
         ``prune`` (with its digest ``pdig``) is the request's pushdown
         expression: a hyperslab pipeline resolves it against per-chunk
@@ -721,12 +742,25 @@ class OSD:
             if isinstance(got, _Negative):
                 meters["neg_hits"] += 1
                 return got.reason, None, 0
-        # miss: digest-verify THIS snapshot's blob, resolve any row
-        # slice against the SAME snapshot's extent, then decode
+        # miss: digest-verify the bytes a decode of THIS snapshot's blob
+        # reads, resolve any row slice against the SAME snapshot's
+        # extent, then decode.  ``cols`` is worked out once: the check
+        # covers it and the decode reads it and nothing else, so an op
+        # that resolution rewrote cannot reach an unchecked extent.
         want = (xattr or {}).get("digest")
-        with span("osd.verify"):
-            bad = want is not None and content_digest(blob) != int(want)
-        if bad:
+        cols = required_columns(ops)
+        with span("osd.verify") as sp:
+            if (cols is not None and want is not None
+                    and "extent_digests" in xattr):
+                # projected: the header and its columns' extents
+                ok, checked = check_extents(
+                    blob, xattr["extent_digests"], cols)
+            else:  # every column, or no extent digests: the whole blob
+                bad = want is not None and content_digest(blob) != int(want)
+                ok, checked = not bad, 0 if want is None else len(blob)
+            sp.set_metadata(bytes=checked)
+        meters["verify_bytes"] += checked
+        if not ok:
             self._quarantine_copy(name)
             return "corrupt", CorruptObject(
                 f"{name} on {self.osd_id}: stored bytes diverge from "
@@ -783,7 +817,7 @@ class OSD:
             scanned = len(blob)
         else:
             table, scanned = self._decoded_table(
-                name, version, blob, resolved, meters)
+                name, version, blob, cols, meters)
             with span("osd.apply"):
                 result = apply_pipeline(table, resolved, encode=encode,
                                         meters=meters)
@@ -850,8 +884,9 @@ class OSD:
         is disjoint from the slice is skipped the same prune-equivalent
         way (combine/concat) or serves zero rows (plain batch).
 
-        Every served copy is verified against its stamped content
-        digest first; a divergent (or torn, under a row slice) copy is
+        Every served copy is digest-verified first, over every byte its
+        pipeline decodes (the extents of a projected read, the whole
+        blob otherwise); a divergent (or torn, under a row slice) copy is
         quarantined and reported in the response's ``corrupt_names`` —
         the client retries those objects on their next replica exactly
         like missing ones, and counts them in
@@ -879,7 +914,7 @@ class OSD:
         thread that issued the call.  Results are served through this
         OSD's :class:`ResultCache` when it is enabled — a hit skips
         digest re-verification, decode, AND the modeled service queue
-        (the entry was derived from a digest-verified blob at the same
+        (the entry was derived from bytes verified at the same
         monotonic version, so the bytes are provably identical), and
         reports 0 scanned bytes because no storage bytes were read.
         """
@@ -1142,6 +1177,7 @@ class ObjectStore:
         f.cache_neg_hits += m.get("neg_hits", 0)
         f.chunks_pruned += m.get("chunks_pruned", 0)
         f.expr_rows += m.get("expr_rows", 0)
+        f.verify_bytes += m.get("verify_bytes", 0)
 
     def io_simulated(self) -> bool:
         """True when requests actually *wait* (NIC/disk bandwidth or OSD
@@ -1400,8 +1436,7 @@ class ObjectStore:
         forwards blob AND xattr together) is independently verifiable
         by reads, ``scrub()`` and ``recover()``."""
         version = self._next_version()
-        stamped = {**(xattr or {}), "version": version,
-                   "digest": content_digest(blob)}
+        stamped = _stamp(blob, xattr, version=version)
         acting = self._acting(name)
         self.fabric.client_tx += len(blob)
         self._account_request()
@@ -1504,8 +1539,7 @@ class ObjectStore:
         if windowed:
             stamped: list[dict | None] = [None] * len(names)
         else:
-            stamped = [{**(x or {}), "version": v,
-                        "digest": content_digest(b)}
+            stamped = [_stamp(b, x, version=v)
                        for x, v, b in zip(xattrs, versions, blobs_l)]
 
         tried: list[set[str]] = [set() for _ in names]
@@ -1718,8 +1752,7 @@ class ObjectStore:
                 blob, x = item if isinstance(item, tuple) \
                     else (item, xattrs[i])
                 blob = bytes(blob)
-                stamped[i] = {**(x or {}), "version": versions[i],
-                              "digest": content_digest(blob)}
+                stamped[i] = _stamp(blob, x, version=versions[i])
                 ledger.pin(i, blob)
                 win.setdefault(self._acting(names[i])[0], []).append(i)
                 win_nbytes += len(blob)
@@ -2502,8 +2535,7 @@ class ObjectStore:
         ``put`` but WITHOUT client fabric accounting — the bytes are
         cluster-internal.  Returns ``(version, bytes_moved)``."""
         version = self._next_version()
-        stamped = {**(xattr or {}), "version": version,
-                   "digest": content_digest(blob)}
+        stamped = _stamp(blob, xattr, version=version)
         acting = self._acting(name)
         self._hop_put(acting[0], name, blob, stamped)
         moved, _, _ = self._replicate(name, blob, stamped, acting)

@@ -12,7 +12,8 @@ returns a shared null context instead.
 Names are ``<layer>.<what>`` (``osd.verify``, ``loader.produce``); a
 span nests under the span its thread has open.  ``meta`` is kept for a
 request identifier (``req=``, ``step=``) that ties spans of one request
-or batch together across threads.
+or batch together across threads, or for a size known only once the
+body has run: ``with span(...) as s: ...; s.set_metadata(bytes=n)``.
 """
 
 from __future__ import annotations
@@ -20,12 +21,25 @@ from __future__ import annotations
 import contextlib
 import sys
 
-_NULL = contextlib.nullcontext()
+
+class _NullSpan(contextlib.nullcontext):
+    """The span while no profiler can run: enters as itself and drops
+    metadata."""
+
+    def __init__(self):
+        super().__init__(self)
+
+    def set_metadata(self, **meta) -> None:
+        pass
+
+
+_NULL = _NullSpan()
 
 
 def span(name: str, **meta):
     """A context manager that records ``name`` (with ``meta``) as a
-    profiler span while its body runs."""
+    profiler span while its body runs; it enters as an object whose
+    ``set_metadata(**meta)`` adds metadata before the span closes."""
     jax = sys.modules.get("jax")
     if jax is None:
         return _NULL

@@ -96,3 +96,35 @@ def test_osd_expr_opens_once_per_object_that_evaluates_an_operand(
     assert omap.n_objects > 1
     assert names.count("osd.expr") == want
     assert names.count("osd.apply") == omap.n_objects
+
+
+def test_osd_verify_carries_the_bytes_it_checked(tmp_path):
+    import jax
+    import numpy as np
+    from jax.profiler import ProfileData
+
+    from repro.core import (Column, GlobalVOL, LogicalDataset,
+                            PartitionPolicy, make_store)
+
+    rng = np.random.default_rng(4)
+    table = {"p": rng.random(2000), "d": rng.random(2000),
+             "q": rng.integers(0, 50, 2000)}
+    store = make_store(3, replicas=2)
+    vol = GlobalVOL(store)
+    ds = LogicalDataset("t", tuple(Column(k, v.dtype.str)
+                                   for k, v in table.items()),
+                        n_rows=2000, unit_rows=250)
+    omap = vol.create(ds, PartitionPolicy(target_object_bytes=8000))
+    vol.write(omap, table)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _, stats = vol.scan("t").filter("d", "<", 0.5).agg(
+            "sum", "q").execute()
+    finally:
+        jax.profiler.stop_trace()
+        store.close()
+    pd = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+    checked = [dict(e.stats)["bytes"] for p in pd.planes for ln in p.lines
+               for e in ln.events if e.name == "osd.verify"]
+    assert len(checked) == omap.n_objects > 1
+    assert sum(checked) == stats["verify_bytes"] > 0

@@ -10,6 +10,7 @@ from repro.checkpoint import ckpt
 from repro.core import (Column, DataLossError, FaultInjector, GlobalVOL,
                         LogicalDataset, PartitionPolicy, RetryPolicy,
                         RowRange, make_store)
+from repro.core import format as fmt
 from repro.core import objclass as oc
 from repro.core.format import content_digest
 from repro.core.store import PartialWriteError
@@ -38,6 +39,42 @@ def _copies_all_verify(store, name):
         assert content_digest(osd.data[name]) == int(x["digest"])
 
 
+def _extent_stamps_match(store, name, block):
+    """Every copy of ``name`` carries extent digests that match its
+    bytes when it is a column-layout block, and none otherwise."""
+    for osd_id in store.cluster.locate(name):
+        osd = store.osds[osd_id]
+        x = osd.xattrs[name]
+        if block:
+            assert x["extent_digests"] == fmt.stamp_digests(
+                osd.data[name])["extent_digests"], (name, osd_id)
+        else:
+            assert "extent_digests" not in x, (name, osd_id)
+
+
+def _extents(blob):
+    """``{"header": (start, stop), <column>: (start, stop), ...}`` of a
+    column-layout block."""
+    hlen = int.from_bytes(blob[4:8], "little")
+    out, off = {"header": (0, 8 + hlen)}, 8 + hlen
+    header = fmt.block_header(blob)
+    for c, n in zip(header["columns"], header["lens"]):
+        out[c["name"]] = (off, off + n)
+        off += n
+    return out
+
+
+def _rot(store, name, osd_id, extent):
+    """Flip one bit in the middle of ``extent`` (a column name or
+    "header") of the copy of ``name`` on ``osd_id``."""
+    osd = store.osds[osd_id]
+    with osd.lock:
+        blob = bytearray(osd.data[name])
+        s, e = _extents(bytes(blob))[extent]
+        blob[(s + e) // 2] ^= 0x10
+        osd.data[name] = bytes(blob)
+
+
 # ------------------------------------------------------- digest substrate
 def test_digest_stamped_on_every_write_path_and_hop():
     store, vol, omap, table = make_world()
@@ -54,6 +91,25 @@ def test_digest_stamped_on_every_write_path_and_hop():
     store.put_batch(names, ((b, None) for b in blobs), window_bytes=4096)
     for name in names:
         _copies_all_verify(store, name)
+    # extent digests: on every copy of every column-layout block, none
+    # on other bytes, and none carried over from another blob's xattr
+    for name in omap.object_names():
+        _extent_stamps_match(store, name, block=True)
+    for name in ["solo", *names]:
+        _extent_stamps_match(store, name, block=False)
+    store.put("solo", b"other bytes", store.xattr(omap.object_names()[0]))
+    _copies_all_verify(store, "solo")
+    _extent_stamps_match(store, "solo", block=False)
+    # the per-object put and the windowed stream stamp blocks too
+    blocks = [vol.local.encode({"v": np.arange(64.0) + i}) for i in range(3)]
+    store.put("blk", blocks[0])
+    store.put_batch(["wb/0", "wb/1"], ((b, None) for b in blocks[1:]),
+                    window_bytes=1)
+    # and the OSD-side compaction, replicated down its chain
+    store.compact_run(omap.object_names()[:2], "cmp")
+    for name in ["blk", "wb/0", "wb/1", "cmp"]:
+        _copies_all_verify(store, name)
+        _extent_stamps_match(store, name, block=True)
 
 
 def test_corrupt_primary_read_fails_over_and_is_counted():
@@ -95,6 +151,121 @@ def test_scans_bit_exact_under_replica_corruption():
     assert np.array_equal(out["y"], table["y"])
     assert np.allclose(out["x"], table["x"])
     assert store.fabric.corruptions_detected >= len(fi.injected)
+
+
+# ------------------------------------------- per-extent read verification
+def test_projected_read_catches_rot_in_a_column_it_decodes():
+    store, vol, omap, table = make_world()
+    name = omap.object_names()[0]
+    prim = store.cluster.locate(name)[0]
+    _rot(store, name, prim, "y")
+    r, stats = vol.scan("t").agg("sum", "y").execute(omap)
+    assert r == float(table["y"].sum())  # failed over: exact
+    assert stats["corruptions_detected"] == 1
+    assert name in store.osds[prim].quarantine
+    assert name not in store.osds[prim].data
+
+
+@pytest.mark.parametrize("later", ["full_read", "scrub"])
+def test_rot_in_a_column_the_read_skips_waits_for_a_read_or_scrub(later):
+    store, vol, omap, table = make_world()
+    name = omap.object_names()[0]
+    prim = store.cluster.locate(name)[0]
+    _rot(store, name, prim, "x")
+    r, stats = vol.scan("t").agg("sum", "y").execute(omap)
+    assert r == float(table["y"].sum())  # no byte of x was decoded
+    assert stats["corruptions_detected"] == 0
+    assert name in store.osds[prim].data  # still in service
+    if later == "full_read":
+        out = vol.read(omap, RowRange(0, len(table["x"])))
+        assert np.array_equal(out["x"], table["x"])
+        assert store.fabric.corruptions_detected == 1
+        assert name in store.osds[prim].quarantine
+    stats = store.scrub()
+    assert stats["corrupt_copies"] == (1 if later == "scrub" else 0)
+    assert stats["healed_copies"] >= 1
+    assert store.fabric.corruptions_detected == 1
+    _copies_all_verify(store, name)
+    _extent_stamps_match(store, name, block=True)
+
+
+@pytest.mark.parametrize("col", ["x", "y"])
+def test_header_rot_is_caught_by_any_projected_read(col):
+    store, vol, omap, table = make_world()
+    name = omap.object_names()[0]
+    prim = store.cluster.locate(name)[0]
+    _rot(store, name, prim, "header")
+    r, stats = vol.scan("t").agg("sum", col).execute(omap)
+    assert r == pytest.approx(float(table[col].sum()), rel=1e-12)
+    assert stats["corruptions_detected"] == 1
+    assert name in store.osds[prim].quarantine
+
+
+@pytest.mark.parametrize("read", ["filter_agg", "project"])
+def test_row_sliced_projected_read_catches_rot_in_a_decoded_column(read):
+    """A row slice resolves on the OSD after the check; the decode still
+    reads only the extents the check covered."""
+    store, vol, omap, table = make_world()
+    name = omap.extents[1].name
+    prim = store.cluster.locate(name)[0]
+    _rot(store, name, prim, "x")
+    blob_bytes = sum(len(store.osds[store.cluster.locate(n)[0]].data[n])
+                     for n in omap.object_names())
+    store.fabric.reset()
+    if read == "filter_agg":
+        r, _ = (vol.scan("t").rows(100, 2500).filter("y", "<", 500)
+                .agg("sum", "x").execute(omap))
+        mask = table["y"][100:2500] < 500
+        assert r == pytest.approx(table["x"][100:2500][mask].sum(),
+                                  rel=1e-12)
+    else:
+        out = vol.read(omap, RowRange(100, 2500), columns=["x"])
+        assert np.array_equal(out["x"], table["x"][100:2500])
+    assert store.fabric.corruptions_detected == 1
+    assert name in store.osds[prim].quarantine
+    assert 0 < store.fabric.verify_bytes < blob_bytes  # extents only
+
+
+@pytest.mark.parametrize("copy", ["no_extent_digests", "row_layout"])
+def test_copy_without_extent_digests_is_checked_whole(copy):
+    store = make_store(4, replicas=2)
+    rng = np.random.default_rng(5)
+    t = {"x": rng.normal(size=500), "y": rng.integers(0, 99, 500)}
+    layout = "row" if copy == "row_layout" else "col"
+    blob = fmt.encode_block(t, layout=layout)
+    store.put("o", blob)
+    acting = store.cluster.locate("o")
+    if copy == "no_extent_digests":  # stamped before extent digests
+        for osd_id in acting:
+            del store.osds[osd_id].xattrs["o"]["extent_digests"]
+        _rot(store, "o", acting[0], "x")
+    else:
+        _extent_stamps_match(store, "o", block=False)
+        osd = store.osds[acting[0]]
+        with osd.lock:  # rot the last byte: the last row's y
+            osd.data["o"] = blob[:-1] + bytes([blob[-1] ^ 1])
+    store.fabric.reset()
+    ops = [oc.op("agg", col="y", fn="sum")]
+    assert oc.combine_partials(ops, store.exec_batch(["o"], ops)) == \
+        t["y"].sum()
+    assert store.fabric.corruptions_detected == 1
+    # the whole blob on the rotten copy, then on the replica
+    assert store.fabric.verify_bytes == 2 * len(blob)
+
+
+def test_verify_bytes_counts_the_extents_a_read_decodes():
+    store, vol, omap, table = make_world()
+    blobs = [store.osds[store.cluster.locate(n)[0]].data[n]
+             for n in omap.object_names()]
+    spans = [_extents(b) for b in blobs]
+    store.fabric.reset()
+    _, stats = vol.scan("t").agg("sum", "y").execute(omap)
+    want = sum(e["header"][1] + e["y"][1] - e["y"][0] for e in spans)
+    assert stats["verify_bytes"] == want
+    assert want < sum(map(len, blobs))
+    store.fabric.reset()
+    vol.read(omap, RowRange(0, len(table["x"])))
+    assert store.fabric.verify_bytes == sum(map(len, blobs))
 
 
 # ------------------------------------------------------- retry layer

@@ -22,8 +22,7 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 import numpy as np
 
 from repro.core import (Column, FaultInjector, GlobalVOL, LogicalDataset,
-                        PartitionPolicy, RowRange, SkyhookDriver,
-                        make_store)
+                        PartitionPolicy, RowRange, make_store)
 
 # -- 1. an 8-OSD cluster, 3-way replication ------------------------------
 store = make_store(8, replicas=3)
@@ -60,11 +59,18 @@ print(f"filter→project: {stats['result_rows']} matching rows back in "
       f"{stats['rx_frames']} framed responses "
       f"({stats['objects_pruned']} objects pruned ON their OSDs)")
 
-drv = SkyhookDriver(vol, n_workers=4)
-med, qstats = drv.execute(drv.scan("sensors")
-                          .median("temp", approx=True))
+med, qstats = vol.scan("sensors").median("temp", approx=True).execute()
 print(f"median(temp) ~= {med:.3f}  [approx sketch, "
-      f"{qstats.client_rx_bytes} B moved, pushdown={qstats.pushdown}]")
+      f"{qstats['client_rx']} B moved, pushdown={qstats['pushdown']}]")
+
+# the no-pushdown baseline: the same pipeline compiled as client-gather
+# (whole objects to the client, evaluated there) — what pushdown saves
+hot_scan = vol.scan("sensors").filter("station", "==", 7).agg("mean", "temp")
+pushed, pstats = hot_scan.execute()
+base, bstats = vol.engine.execute(vol.engine.compile_ops(
+    omap, hot_scan.pipeline(), baseline=True))
+print(f"client-side baseline: mean {base:.3f} (pushdown {pushed:.3f}), "
+      f"{bstats['client_rx']} B moved against {pstats['client_rx']} B")
 
 # -- 3b. expression filters + OSD-side row ranges --------------------------
 # filters are a full predicate ALGEBRA (core.expr): OR-groups, IN-lists,
@@ -98,8 +104,7 @@ print(f"rows[10k:60k] mean(temp|>20) = {windowed:.2f}  "
 # STREAMS: per-OSD sub-write groups flush as the encoder produces
 # blobs, so encode overlaps the NIC instead of running ahead of it, and
 # each replica write pipelines entry -> replica -> replica (chain), so
-# the entry OSD sends each blob once.  (table1_forwarding measures
-# ~1.7x over buffered encode-then-stream at the 192 MB scale.)
+# the entry OSD sends each blob once.
 sim = make_store(4, replicas=3, client_bw=400 << 20, disk_bw=200 << 20)
 svol = GlobalVOL(sim)
 sds = LogicalDataset("stream_demo",

@@ -29,7 +29,7 @@ class Finding:
     #                 "write-path" | "registry" | ...
     file: str       # repo-relative path
     line: int
-    qualname: str   # e.g. "ObjectStore.put", "SkyhookDriver.run.pump"
+    qualname: str   # e.g. "ObjectStore.put", "ObjectStore._stream_put.stream_group.feed"
     message: str
 
     @property

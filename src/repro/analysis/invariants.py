@@ -57,7 +57,6 @@ VAR_TYPES: dict[str, str] = {
     "cache": "ResultCache",
     "session": "ScanSession",
     "maintenance": "MaintenancePlane",
-    "w": "SkyhookWorker",
 }
 
 # attribute names whose subscript yields an OSD (``self.osds[osd_id]``)

@@ -249,7 +249,7 @@ def _wrap_container(value, name: str, owner: InstrumentedLock,
 
 _CORE_MODULES = ("repro.core.store", "repro.core.cache",
                  "repro.core.session", "repro.core.maintenance",
-                 "repro.core.faults", "repro.core.skyhook")
+                 "repro.core.faults")
 
 
 def _instrument_instance(self, state: LockCheckState) -> None:

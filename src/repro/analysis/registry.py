@@ -7,7 +7,7 @@ registry is data, so the honest check is to interrogate the real one:
   survives a wire round trip (``to_json -> json -> from_json``) with an
   identical pipeline digest — an op that can't cross the wire can't be
   pushed down;
-* every op either rides a server-side merge plane (``exec_combine``:
+* every op either rides a server-side merge plane (``exec_combine_iter``:
   decomposable + combine + merge, partial-out) or the concat plane
   (table-out), **or** is explicitly declared not mergeable;
 * every op's column needs are either analyzable by

@@ -21,7 +21,6 @@ Layering (bottom up):
   session    — ScanSession: many-client admission front-end
                (single-flight dedup + projection coalescing)
   vol        — GlobalVOL (client plugin) / LocalVOL (storage plugin)
-  skyhook    — driver/worker scheduling over the scan engine
   pushdown_jax — the TPU data plane: compute-at-shard via shard_map
 """
 
@@ -42,4 +41,3 @@ from repro.core.cache import ResultCache  # noqa: F401
 from repro.core.scan import PhysicalPlan, Scan, ScanEngine  # noqa: F401
 from repro.core.session import ScanSession  # noqa: F401
 from repro.core.vol import ArrayView, GlobalVOL, LocalVOL  # noqa: F401
-from repro.core.skyhook import Query, SkyhookDriver  # noqa: F401

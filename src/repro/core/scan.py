@@ -17,26 +17,29 @@ object-mapped dataset.  This module is where that promise lives:
                           .execute()
 
   * :class:`PhysicalPlan` — what a ``Scan`` compiles to: the storage
-    pipeline, the prune strategy, the execution class, and the per-OSD
-    request shards.  ``Scan.explain()`` returns it for inspection.
+    pipeline, the prune strategy and the execution class.
+    ``Scan.explain()`` returns it for inspection.
 
-  * :class:`ScanEngine` — the ONE executor.  ``GlobalVOL.read`` /
-    ``GlobalVOL.query``, ``SkyhookDriver.execute`` (and its client-side
-    baseline), and the training-data loader all route through it; the
+  * :class:`ScanEngine` — the ONE executor.  ``Scan.execute``,
+    ``GlobalVOL.read`` / ``GlobalVOL.query`` (and the client-side
+    baseline, ``compile_ops(..., baseline=True)``), and the
+    training-data loader all route through it; the
     tail/combine/holistic/approx-rewrite decision exists nowhere else.
 
 Execution classes
 -----------------
 ``osd-combine``      mergeable aggregate tails: each OSD folds its local
-                     partials (``exec_combine``) — client_rx O(K).
+                     partials (``exec_combine_iter``) — client_rx O(K).
 ``server-concat``    table-out pipelines: each OSD concatenates its
                      result tables into ONE framed block
-                     (``exec_concat``) — rx_frames O(K).
+                     (``exec_concat_iter``) — rx_frames O(K).
 ``holistic-gather``  exact median: filters/projection still run
                      storage-side (as a server-concat of the projected
                      column), the holistic tail runs client-side.
+``partial-gather``   partial tails the OSD cannot fold: per-object
+                     partials (``exec_batch_iter``), folded client-side.
 ``table-gather``     per-object raw results (e.g. zero-decode
-                     ``select_packed``) via ``exec_batch``.
+                     ``select_packed``) via ``exec_batch_iter``.
 ``client-gather``    the no-pushdown baseline: full objects to the
                      client, pipeline evaluated locally.
 
@@ -110,7 +113,7 @@ class Scan:
 
     A ``Scan`` built through ``GlobalVOL.scan`` is *bound* (it knows its
     vol and can ``explain()``/``execute()`` itself); a bare
-    ``Scan(dataset=...)`` is a pure value that a driver executes.
+    ``Scan(dataset=...)`` is a pure value until ``bind(vol)``.
     """
 
     dataset: str | None = None
@@ -122,8 +125,6 @@ class Scan:
     row_range: tuple[int, int] | None = None
     prune_strategy: str = "auto"
     _vol: Any = dataclasses.field(default=None, compare=False, repr=False)
-    _runner: Any = dataclasses.field(default=None, compare=False,
-                                     repr=False)
 
     # ------------------------------------------------------------ fluent
     def filter(self, col: str, cmp: str, value) -> "Scan":
@@ -209,10 +210,9 @@ class Scan:
                              f"known: {PRUNE_STRATEGIES}")
         return dataclasses.replace(self, prune_strategy=strategy)
 
-    def bind(self, vol, runner=None) -> "Scan":
-        """Attach the executing vol (and optionally a scheduling runner
-        — e.g. a driver's worker dispatcher) to this scan."""
-        return dataclasses.replace(self, _vol=vol, _runner=runner)
+    def bind(self, vol) -> "Scan":
+        """Attach the executing vol to this scan."""
+        return dataclasses.replace(self, _vol=vol)
 
     # ------------------------------------------------------------ compile
     def pipeline(self) -> list[oc.ObjOp]:
@@ -240,7 +240,7 @@ class Scan:
     def _bound(self, omap=None):
         if self._vol is None:
             raise ValueError("unbound Scan — build it via vol.scan(...) "
-                             "or hand it to a SkyhookDriver")
+                             "or bind(vol)")
         if omap is None:
             omap = self._vol.open(self.dataset)
         return self._vol.engine, omap
@@ -252,8 +252,7 @@ class Scan:
     def execute(self, omap=None) -> tuple[Any, dict]:
         engine, omap = self._bound(omap)
         before = self._vol.store.fabric.snapshot()
-        return engine.execute(engine.compile(omap, self),
-                              runner=self._runner, before=before,
+        return engine.execute(engine.compile(omap, self), before=before,
                               omap=omap)
 
 
@@ -264,7 +263,7 @@ def _wire(col):
 
 
 def scan(dataset: str) -> Scan:
-    """An unbound scan over a named dataset (bind via a vol/driver)."""
+    """An unbound scan over a named dataset (``bind(vol)`` to run it)."""
     return Scan(dataset=dataset)
 
 
@@ -293,7 +292,6 @@ class PhysicalPlan:
     predicates: Any = None           # expr.Expr tree pushed to the OSDs
     #                                  when prune == "pushdown"
     pruned: tuple[str, ...] = ()     # client-side pruned at compile time
-    shards: tuple = ()               # ((osd_id, (name idx, ...)), ...)
     pushdown: bool = False           # pipeline ops run storage-side?
     approx_rewrite: bool = False
     assemble: str = "table"          # "table" | "parts" (loader) |
@@ -318,29 +316,14 @@ class PhysicalPlan:
 
 class ScanEngine:
     """Compiles scans/pipelines to :class:`PhysicalPlan` and executes
-    them against the store.
+    them against the store's per-OSD read planes (``exec_batch_iter``,
+    ``exec_combine_iter``, ``exec_concat_iter``), which group objects
+    by primary OSD themselves.
 
-    ``execute`` takes an optional ``runner`` — the driver passes a
-    worker-sharding dispatcher (Fig. 4), everything else uses the
-    store's own per-OSD batch plane directly.  A runner is transport
-    only: it must preserve the store-call semantics, never re-decide
-    the plan.
-
-    Runner protocol: ``runner(mode, names, pipelines, predicates,
-    shards)`` where mode is ``"combine"`` → ``(partials,
-    pruned_names)``, ``"concat"`` → ``(frames, pruned_names)`` with
-    frames ``(global_indices, blob, row_counts)``, or ``"batch"`` →
-    per-object results aligned with ``names``.  ``shards`` is the
-    plan's per-OSD grouping (``(osd_id, name_indices)`` pairs) so a
-    scheduling runner need not re-derive placement.
-
-    The ``partials`` / ``frames`` half of a combine/concat response may
-    be LAZY — an iterator that yields per-OSD results as they land
-    (the store's ``exec_*_iter`` planes, or a driver streaming shard
-    results in worker-completion order).  The engine consumes it
-    frame-by-frame, decoding/folding each result while slower OSDs are
-    still scanning, and reads ``pruned_names`` (which may fill during
-    iteration) only after exhaustion.
+    Combine partials are folded in dispatch order, so the float fold
+    is bit-deterministic run to run; concat frames are decoded as they
+    arrive, while slower OSDs are still scanning.  OSD-pruned names are
+    read only once a plane's iterator is exhausted.
     """
 
     def __init__(self, vol):
@@ -355,8 +338,9 @@ class ScanEngine:
     def compile_ops(self, omap, ops: Sequence[oc.ObjOp], *,
                     allow_approx: bool = False, prune: str = "auto",
                     baseline: bool = False) -> PhysicalPlan:
-        """Compile a raw objclass pipeline (the ``GlobalVOL.query`` /
-        ``Query`` shim entry point)."""
+        """Compile a raw objclass pipeline (the ``GlobalVOL.query``
+        entry point; ``baseline=True`` is the no-pushdown client-gather
+        plan)."""
         return self._compile(omap, list(ops), allow_approx=allow_approx,
                              prune=prune, baseline=baseline)
 
@@ -468,12 +452,6 @@ class ScanEngine:
                                            EXEC_PARTIAL_GATHER):
             access = "scan"
 
-        by_osd: dict[str, list[int]] = {}
-        if not baseline:
-            cluster = self.vol.store.cluster
-            for i, n in enumerate(names):
-                by_osd.setdefault(cluster.primary(n), []).append(i)
-
         return PhysicalPlan(
             dataset=omap.dataset.name,
             exec_cls=exec_cls,
@@ -484,8 +462,6 @@ class ScanEngine:
             pipelines=None,
             predicates=predicates if prune_s == "pushdown" else None,
             pruned=pruned,
-            shards=tuple(sorted(
-                (osd, tuple(idxs)) for osd, idxs in by_osd.items())),
             pushdown=exec_cls in (
                 EXEC_OSD_COMBINE, EXEC_SERVER_CONCAT,
                 EXEC_PARTIAL_GATHER, EXEC_TABLE_GATHER),
@@ -526,10 +502,6 @@ class ScanEngine:
                 if prune != "none" else None
             targets = amap.lookup(hs)
             names = [e.name for e, _ in targets]
-            by_osd: dict[str, list[int]] = {}
-            cluster = self.vol.store.cluster
-            for i, n in enumerate(names):
-                by_osd.setdefault(cluster.primary(n), []).append(i)
             ops = (oc.op("hyperslab_slice", space=space.to_json(),
                          sel=hs.to_json()),)
             return PhysicalPlan(
@@ -540,8 +512,6 @@ class ScanEngine:
                 ops=ops,
                 exec_ops=ops,
                 predicates=pred,
-                shards=tuple(sorted(
-                    (osd, tuple(idxs)) for osd, idxs in by_osd.items())),
                 pushdown=True,
                 assemble="array",
                 access="fetch",
@@ -610,8 +580,8 @@ class ScanEngine:
         return self._compile(fresh, list(plan.ops),
                              prune=plan.prune, access=plan.access)
 
-    def execute(self, plan: PhysicalPlan, runner=None,
-                before: dict | None = None, omap=None) -> tuple[Any, dict]:
+    def execute(self, plan: PhysicalPlan, before: dict | None = None,
+                omap=None) -> tuple[Any, dict]:
         """Run one compiled plan; returns ``(result, stats)`` with the
         unified stats emission every caller shares.  ``before`` lets the
         caller open the fabric-accounting window ahead of ``compile`` so
@@ -622,12 +592,11 @@ class ScanEngine:
         callers that just compiled against a map they hold pass it so a
         matching version skips the refresh probe entirely."""
         with span("front.request", req=next(_REQ_IDS)):
-            return self._execute(plan, runner, before, omap)
+            return self._execute(plan, before, omap)
 
-    def _execute(self, plan: PhysicalPlan, runner, before: dict | None,
+    def _execute(self, plan: PhysicalPlan, before: dict | None,
                  omap) -> tuple[Any, dict]:
         store = self.vol.store
-        run = runner or self._direct
         if before is None:
             before = store.fabric.snapshot()
         plan = self._refresh(plan, omap)
@@ -636,47 +605,41 @@ class ScanEngine:
         pipes = [list(p) for p in plan.pipelines] \
             if plan.pipelines is not None else list(plan.exec_ops)
         preds = plan.predicates
+        # OSD-pruned names, complete once a plane's iterator is drained
         osd_pruned: list[str] = []
         result_rows: int | None = None
 
-        shards = plan.shards
-
         if plan.exec_cls == EXEC_OSD_COMBINE:
-            partials_src, pruned_src = run("combine", names, pipes,
-                                           preds, shards)
-            # consume lazily: each OSD's partial folds in as it lands
-            partials = list(partials_src)
-            osd_pruned = list(pruned_src)
+            partials = list(store.exec_combine_iter(
+                names, pipes, prune=preds, pruned_out=osd_pruned))
             with span("front.assemble"):
                 result = oc.combine_partials(ops, partials)
             result_rows = 1
         elif plan.exec_cls == EXEC_PARTIAL_GATHER:
-            raw = run("batch", names, pipes, None, shards)
+            raw = _by_position(len(names),
+                               store.exec_batch_iter(names, pipes))
             with span("front.assemble"):
                 result = oc.combine_partials(ops, raw)
             result_rows = 1
         elif plan.exec_cls == EXEC_HOLISTIC_GATHER:
             col = ops[-1].params["col"]
-            frames_src, pruned_src = run("concat", names, pipes, preds,
-                                         shards)
             # frame-by-frame: decode each OSD's block on arrival, while
             # slower OSDs are still scanning
             cols = []
-            for _, blob, _ in frames_src:
+            for _, blob, _ in store.exec_concat_iter(
+                    names, pipes, prune=preds, pruned_out=osd_pruned):
                 with span("front.assemble"):
                     cols.append({col: fmt.decode_block(blob)[col].ravel()})
-            osd_pruned = list(pruned_src)
             with span("front.assemble"):
                 result = oc.median_exact(cols, col)
             result_rows = 1
         elif plan.exec_cls == EXEC_SERVER_CONCAT:
-            frames_src, pruned_src = run("concat", names, pipes, preds,
-                                         shards)
             parts: list = [None] * len(names)
-            for frame in frames_src:  # decode overlaps slower OSDs
+            # decode each frame on arrival, overlapping slower OSDs
+            for frame in store.exec_concat_iter(
+                    names, pipes, prune=preds, pruned_out=osd_pruned):
                 with span("front.assemble"):
                     _place_frame(parts, frame)
-            osd_pruned = list(pruned_src)
             if plan.assemble == "parts":
                 result = parts
             elif plan.assemble == "array":
@@ -689,7 +652,8 @@ class ScanEngine:
                         [p for p in parts if p is not None])
                 result_rows = oc.table_n_rows(result)
         elif plan.exec_cls == EXEC_TABLE_GATHER:
-            result = run("batch", names, pipes, None, shards)
+            result = _by_position(len(names),
+                                  store.exec_batch_iter(names, pipes))
         elif plan.exec_cls == EXEC_CLIENT_GATHER:
             result = self._client_eval(names, ops)
             result_rows = _result_rows(ops, result)
@@ -731,8 +695,8 @@ class ScanEngine:
         result)`` pairs the moment their per-OSD frame lands and
         decodes, in arrival order — the loader's windowed consume.  A
         consumer holding results for early indices finishes before the
-        slowest OSD responds; results are bit-identical to the buffered
-        gather."""
+        slowest OSD responds; results are bit-identical to
+        ``fetch_objects``."""
         store = self.vol.store
         plan = self.compile_gather(names, pipelines, packed=packed)
         pipes = [list(p) for p in plan.pipelines]
@@ -743,21 +707,6 @@ class ScanEngine:
             yield from _iter_frame(frame)
 
     # ------------------------------------------------------------ internals
-    def _direct(self, mode, names, pipelines, predicates, shards=()):
-        del shards  # the store regroups by primary OSD itself
-        store = self.vol.store
-        if mode == "combine":
-            pruned: list[str] = []
-            return store.exec_combine_iter(
-                names, pipelines, prune=predicates,
-                pruned_out=pruned), pruned
-        if mode == "concat":
-            pruned = []
-            return store.exec_concat_iter(
-                names, pipelines, prune=predicates,
-                pruned_out=pruned), pruned
-        return store.exec_batch(names, pipelines)
-
     def _client_eval(self, names, ops):
         """The no-pushdown baseline: whole objects to the client, the
         pipeline evaluated locally (byte accounting shows what pushdown
@@ -776,6 +725,15 @@ class ScanEngine:
             else:
                 result = impl.local(result, **o.params)
         return result
+
+
+def _by_position(n: int, pairs) -> list:
+    """Place a batch plane's ``(index, result)`` pairs at their input
+    positions."""
+    out: list[Any] = [None] * n
+    for i, r in pairs:
+        out[i] = r
+    return out
 
 
 def _split_frames(n: int, frames) -> list:

@@ -119,7 +119,7 @@ class ScanSession:
         """Run one scan through the session: join an open (or still
         compatible) flight when one exists, otherwise lead a new one.
         Returns ``(result, stats)`` exactly like ``Scan.execute``."""
-        scan = scan.bind(self.vol, scan._runner)
+        scan = scan.bind(self.vol)
         key, cols = self._identity(scan)
         with self._lock:
             self.stats["admitted"] += 1

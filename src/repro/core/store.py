@@ -4,18 +4,20 @@ OSDs are in-process shards with byte-accurate transfer accounting; the
 semantics — primary/replica writes, objclass execution on the primary,
 failure, peering/recovery — follow Ceph.  The accounting (client<->OSD
 bytes vs OSD-local bytes processed) is what the paper's pushdown claims
-are measured against in ``benchmarks/``.
+are measured against.
 
 Symmetric batched data plane: EVERY client<->OSD interaction goes
 through one per-OSD batch RPC, so fabric ops scale with the number of
 OSDs touched (K), never the number of objects (N):
 
-  * reads/scans — ``exec_batch(names, ops)`` groups objects by primary
-    OSD, ONE objclass request per OSD; ``ops`` may be a single shared
-    pipeline or one pipeline per object;
-  * aggregate scans — ``exec_combine(names, ops)`` additionally folds
-    partials *on* each OSD (the tail op's associative ``merge``) and
-    returns ONE partial per OSD, so ``client_rx`` is O(K) too;
+  * reads/scans — ``exec_batch_iter(names, ops)`` groups objects by
+    primary OSD, ONE objclass request per OSD; ``ops`` may be a single
+    shared pipeline or one pipeline per object;
+  * aggregate scans — ``exec_combine_iter(names, ops)`` additionally
+    folds partials *on* each OSD (the tail op's associative ``merge``)
+    and yields ONE partial per OSD, so ``client_rx`` is O(K) too;
+  * table scans — ``exec_concat_iter(names, ops)`` concatenates each
+    OSD's result tables into ONE framed block per OSD;
   * writes — ``put_batch(names, blobs, xattrs)`` groups sub-writes by
     primary OSD (one request + server-side replication per object),
     with per-object failover inside the batch;
@@ -28,11 +30,11 @@ O(overlap) wall-clock plane.  ``put_batch(window_bytes=...)`` accepts a
 lazy blob producer and flushes per-OSD sub-write groups into one
 long-lived streaming request per primary OSD as each window fills, so
 client-side encode overlaps the NIC stream (measured in
-``Fabric.overlap_s`` / ``stream_windows``); ``exec_batch_iter`` /
-``exec_combine_iter`` / ``exec_concat_iter`` are the read-side twins —
-per-OSD result frames are delivered in completion order so the client
-decodes early frames while slower OSDs are still scanning.  Replica
-writes pipeline down a CHAIN (entry -> replica -> replica, Ceph's
+``Fabric.overlap_s`` / ``stream_windows``).  The read planes are the
+read-side half: each is an iterator, and per-OSD results are delivered
+as their requests land (concat and batch in completion order) so the
+client decodes early frames while slower OSDs are still scanning.
+Replica writes pipeline down a CHAIN (entry -> replica -> replica, Ceph's
 primary-copy forwarding) instead of fanning out, halving the entry
 OSD's replication egress (``Fabric.entry_egress_bytes``) at 3x
 replication; ``replication="fanout"`` keeps the legacy topology for
@@ -150,7 +152,7 @@ class Fabric:
     concurrently (a prefetching data loader beside an async
     checkpointer, say) interleave their updates without synchronization
     — read invariants around single-threaded windows, as the tests and
-    benchmarks do."""
+    the chip benchmark (``bench/``) do."""
 
     client_tx: int = 0          # client -> OSD (writes)
     client_rx: int = 0          # OSD -> client (reads / results)
@@ -165,7 +167,7 @@ class Fabric:
     xattr_ops: int = 0          # metadata (xattr) lookups
     rx_frames: int = 0          # framed result payloads the client parsed
     stream_windows: int = 0     # windowed sub-write groups flushed +
-    #                             result frames delivered while streaming
+    #                             read-plane results delivered
     overlap_s: float = 0.0      # encode time hidden behind an active
     #                             NIC stream (windowed ingest)
     scrub_bytes: int = 0        # bytes digest-verified by scrub walks
@@ -863,8 +865,7 @@ class OSD:
 
     def exec_cls_batch(
             self, items: Sequence[tuple[str, list[ObjOp]]],
-            combine: bool = False, concat: bool = False,
-            prune=None) -> Any:
+            mode: str = "batch", prune=None) -> Any:
         """One batched objclass request: run each (name, pipeline) item
         against local data.  The per-request latency is paid ONCE for
         the whole batch — that is the round-trip amortization batching
@@ -878,11 +879,12 @@ class OSD:
         and skips objects the expression provably cannot match — the
         pruned names ride back in the response (they are a semantic
         skip, not an absence, so the client must not fail them over).
-        Only the combine/concat forms accept it (plain responses are
-        positional).  A ``row_slice`` op in a pipeline is resolved here
-        against each object's own extent xattr; an object whose extent
-        is disjoint from the slice is skipped the same prune-equivalent
-        way (combine/concat) or serves zero rows (plain batch).
+        Only the ``"combine"``/``"concat"`` modes accept it (``"batch"``
+        responses are positional).  A ``row_slice`` op in a pipeline is
+        resolved here against each object's own extent xattr; an object
+        whose extent is disjoint from the slice is skipped the same
+        prune-equivalent way (combine/concat) or serves zero rows
+        (batch).
 
         Every served copy is digest-verified first, over every byte its
         pipeline decodes (the extents of a projected read, the whole
@@ -892,7 +894,10 @@ class OSD:
         like missing ones, and counts them in
         ``Fabric.corruptions_detected``.
 
-        With ``combine=True`` the items must share one decomposable
+        ``mode="batch"`` returns one ``(result, scanned_bytes)`` (or an
+        exception value) per item, in item order.
+
+        With ``mode="combine"`` the items must share one decomposable
         pipeline whose tail has an associative ``merge``: the OSD folds
         its local partials into ONE and returns a
         ``(partial|None, n_found, scanned_bytes, missing_names,
@@ -900,7 +905,7 @@ class OSD:
         the OSD per request, not one per object (the server-side half
         of the two-level combine).
 
-        With ``concat=True`` every item's pipeline must be table-out:
+        With ``mode="concat"`` every item's pipeline must be table-out:
         the OSD concatenates the per-object result tables (item order)
         and encodes them as ONE framed block, returning
         ``(blob|None, served_indices, row_counts, scanned_bytes,
@@ -919,12 +924,11 @@ class OSD:
         reports 0 scanned bytes because no storage bytes were read.
         """
         with span("osd.serve"):
-            return self._serve_batch(items, combine, concat, prune)
+            return self._serve_batch(items, mode, prune)
 
-    def _serve_batch(self, items, combine: bool, concat: bool,
-                     prune) -> Any:
-        if combine and concat:
-            raise ValueError("combine and concat are exclusive")
+    def _serve_batch(self, items, mode: str, prune) -> Any:
+        if mode not in ("batch", "combine", "concat"):
+            raise ValueError(f"unknown exec_cls_batch mode {mode!r}")
         self._touch()
         prune = ex.ensure_pred(prune)  # parse the wire form ONCE
         # ...and likewise each pipeline's serialized filter trees (a
@@ -954,10 +958,10 @@ class OSD:
                 d = digs.setdefault(id(ops), pipeline_digest(ops))
             return d
 
-        if not combine and not concat:
+        if mode == "batch":
             if prune is not None:
-                raise ValueError("prune needs combine or concat "
-                                 "(plain batch responses are positional)")
+                raise ValueError("prune needs mode combine or concat "
+                                 "(batch responses are positional)")
             out: list[Any] = []
             for name, ops in items:
                 status, payload, scanned = self._serve_item(
@@ -974,7 +978,7 @@ class OSD:
         missing: list[str] = []
         corrupt: list[str] = []
         scanned = 0
-        if concat:
+        if mode == "concat":
             tables: list[dict] = []
             served: list[int] = []
             counts: list[int] = []
@@ -1126,7 +1130,7 @@ class ObjectStore:
             thread_name_prefix="store-io")
         # hedged reads get their own small persistent pool: an abandoned
         # straggler parks on a worker for its full latency and must not
-        # starve exec_batch dispatch on the main pool
+        # starve read-plane dispatch on the main pool
         self._hedge_pool = ThreadPoolExecutor(
             max_workers=4, thread_name_prefix="store-hedge")
         # observability for the write ledger: the peak retained-blob
@@ -1366,29 +1370,24 @@ class ObjectStore:
         return results
 
     def _scatter_iter(self, names: list[str], run_group, handle,
-                      stream: bool = False,
-                      completion_order: bool | None = None
-                      ) -> Iterator[Any]:
+                      completion_order: bool = True) -> Iterator[Any]:
         """The shared replica-failover skeleton of the batched read
-        planes (``exec_batch`` / ``exec_combine`` / ``exec_concat``),
-        as a generator: group pending items by their next untried
-        acting OSD, dispatch one batched request per group, account the
-        round trip, and let ``handle`` consume each per-group response
-        — returning ``(retry_indices, emitted_items)``.  Under
-        ``completion_order`` (default: follows ``stream``) emitted
-        items are yielded the moment THEIR group's response lands, so a
-        streaming consumer decodes early frames while slower OSDs are
-        still scanning; otherwise groups are consumed in dispatch
-        (sorted-OSD) order, which keeps order-sensitive reductions —
-        float partial folds — bit-deterministic run to run.  Under
-        ``stream=True`` each delivered item also counts in
+        planes (``exec_batch_iter`` / ``exec_combine_iter`` /
+        ``exec_concat_iter``), as a generator: group pending items by
+        their next untried acting OSD, dispatch one batched request per
+        group, account the round trip, and let ``handle`` consume each
+        per-group response — returning ``(retry_indices,
+        emitted_items)``.  Under ``completion_order`` emitted items are
+        yielded the moment THEIR group's response lands, so a consumer
+        decodes early frames while slower OSDs are still scanning;
+        otherwise groups are consumed in dispatch (sorted-OSD) order,
+        which keeps order-sensitive reductions — float partial folds —
+        bit-deterministic run to run.  Each delivered item counts in
         ``Fabric.stream_windows``.  A whole-request failure (OSD down)
         retries every item of its group.  Each group's round trip and
         its handling run in a ``store.request`` span (inline, the OSD's
         serve nests in it), closed before any of its items is
         yielded."""
-        if completion_order is None:
-            completion_order = stream
         tried: list[set[str]] = [set() for _ in names]
         last_err: list[Exception | None] = [None] * len(names)
         pending = list(range(len(names)))
@@ -1421,8 +1420,7 @@ class ObjectStore:
                     retry, emitted = handle(idxs, got, last_err)
                 pending.extend(retry)
                 for item in emitted:
-                    if stream:
-                        self.fabric.stream_windows += 1
+                    self.fabric.stream_windows += 1
                     yield item
 
     # ------------------------------------------------------------ client IO
@@ -1466,7 +1464,7 @@ class ObjectStore:
         ``entry_egress_bytes``).  Objects whose group request failed
         (entry OSD down mid-batch) are re-grouped onto their next
         untried replica and retried as fresh batched requests —
-        per-object failover inside the batch, mirroring ``exec_batch``.
+        per-object failover inside the batch, mirroring the read planes.
 
         **Windowed streaming mode** (``window_bytes`` and/or
         ``window_objects``): ``blobs`` may be a lazy iterable — a
@@ -1940,8 +1938,9 @@ class ObjectStore:
                 census=self.copy_census([name]))
         raise err if err else ObjectNotFound(name)
 
-    def exec_batch(self, names: Iterable[str],
-                   ops: list[ObjOp] | Sequence[list[ObjOp]]) -> list[Any]:
+    def exec_batch_iter(self, names: Iterable[str],
+                        ops: list[ObjOp] | Sequence[list[ObjOp]]
+                        ) -> Iterator[tuple[int, Any]]:
         """Batched objclass execution: ONE request per involved OSD.
 
         Objects are grouped by their primary OSD and each group goes out
@@ -1950,41 +1949,19 @@ class ObjectStore:
         objects.  ``ops`` is either one pipeline applied to every object
         or a per-object sequence of pipelines (``len == len(names)``).
 
-        Failover: objects whose request failed (OSD down, replica
-        missing the object) are re-grouped onto their next untried
-        replica and retried as fresh batched requests; per-object
-        results are returned in input order, bit-identical to the
-        per-object ``exec`` path.
+        Yields ``(index, result)`` pairs (``index`` into ``names``) the
+        moment their per-OSD group response lands (completion order),
+        so the consumer decodes early results while slower OSDs are
+        still scanning; a caller that needs results by position places
+        them itself.  Failover: objects whose request failed (OSD down,
+        replica missing the object) are re-grouped onto their next
+        untried replica and retried as fresh batched requests; each
+        result is bit-identical to the per-object ``exec`` path.
         """
-        gen, results = self._exec_batch_impl(names, ops)
-        for _ in gen:
-            pass
-        return results
-
-    def exec_batch_iter(self, names: Iterable[str],
-                        ops: list[ObjOp] | Sequence[list[ObjOp]]
-                        ) -> Iterator[tuple[int, Any]]:
-        """Streaming twin of ``exec_batch``: yields ``(index, result)``
-        pairs the moment their per-OSD group response lands (completion
-        order), so the consumer decodes early results while slower OSDs
-        are still scanning.  Same requests, failover, and accounting as
-        the buffered form; delivered results count in
-        ``Fabric.stream_windows``."""
-        gen, _ = self._exec_batch_impl(names, ops, stream=True)
-        return gen
-
-    def _exec_batch_impl(self, names, ops, stream: bool = False):
         names = list(names)
-        results: list[Any] = [None] * len(names)
         if not names:
-            return iter(()), results
-        if ops and isinstance(ops[0], (list, tuple)):
-            pipelines = [list(p) for p in ops]
-            if len(pipelines) != len(names):
-                raise ValueError(
-                    f"{len(pipelines)} pipelines for {len(names)} objects")
-        else:
-            pipelines = [list(ops)] * len(names)
+            return iter(())
+        pipelines = _per_object(names, ops)
 
         def run_group(osd_id: str, idxs: list[int]) -> Any:
             try:
@@ -2011,17 +1988,16 @@ class ObjectStore:
                 self.fabric.local_bytes += scanned
                 group_rx += _result_nbytes(result)
                 self.fabric.rx_frames += 1
-                results[i] = result
                 emitted.append((i, result))
             self.fabric.client_rx += group_rx
             self._client_xfer(group_rx)
             return retry, emitted
 
-        gen = self._scatter_iter(names, run_group, handle, stream=stream)
-        return gen, results
+        return self._scatter_iter(names, run_group, handle)
 
-    def exec_combine(self, names: Iterable[str], ops: list[ObjOp],
-                     prune=None) -> Any:
+    def exec_combine_iter(self, names: Iterable[str], ops: list[ObjOp],
+                          prune=None, pruned_out: list | None = None
+                          ) -> Iterator[Any]:
         """Batched pushdown with SERVER-SIDE combine.
 
         Each involved OSD runs the (shared, decomposable) pipeline over
@@ -2029,59 +2005,43 @@ class ObjectStore:
         op's associative ``merge``, and returns ONE partial — so an
         N-object aggregate scan over K OSDs moves K partials
         (``client_rx`` O(K)) in K round trips, instead of N partials in
-        K round trips (``exec_batch``) or N in N (per-object ``exec``).
+        K round trips (``exec_batch_iter``) or N in N (per-object
+        ``exec``).
 
         Objects missing from an OSD fail over to the next replica in
-        their acting set exactly like ``exec_batch``.  Returns one
+        their acting set exactly like ``exec_batch_iter``.  Yields one
         merged partial per issued request that found at least one
         object; finish with ``objclass.combine_partials`` (merged
-        partials are shape-identical to raw ones).
+        partials are shape-identical to raw ones).  Partials are
+        scalar-sized (there is no decode to overlap), so delivery keeps
+        DISPATCH order — a float fold over the yields is
+        bit-deterministic run to run.
 
         ``prune`` pushes a filter-expression tree (an ``expr.Expr`` —
         OR-groups, IN-lists, ranges, prefixes — its wire dict, or the
         legacy tuple of (col, cmp, value) triples) down with each
         request, serialized by ``_prune_wire``: the OSD skips objects
         whose CURRENT local zone map proves the expression matches
-        nothing, and the call returns ``(partials, pruned_names)``
-        instead of the bare partial list.  Pruned objects are a
-        semantic skip — they are NOT retried on replicas.
+        nothing.  Pruned objects are a semantic skip — they are NOT
+        retried on replicas — and their names accumulate into
+        ``pruned_out`` (complete once the iterator is exhausted).
         """
-        gen, pruned_out = self._exec_combine_impl(names, ops, prune)
-        partials = list(gen)
-        return (partials, pruned_out) if prune is not None else partials
-
-    def exec_combine_iter(self, names: Iterable[str], ops: list[ObjOp],
-                          prune=None, pruned_out: list | None = None
-                          ) -> Iterator[Any]:
-        """Streaming twin of ``exec_combine``: yields each OSD's merged
-        partial as the scatter progresses.  Partials are scalar-sized
-        (there is no decode to overlap), so delivery keeps DISPATCH
-        order — a float fold over the yields is bit-deterministic run
-        to run, unlike a completion-order stream.  OSD-pruned names
-        accumulate into ``pruned_out`` (complete once the iterator is
-        exhausted)."""
-        gen, _ = self._exec_combine_impl(names, ops, prune, stream=True,
-                                         pruned_out=pruned_out)
-        return gen
-
-    def _exec_combine_impl(self, names, ops, prune, stream: bool = False,
-                           pruned_out: list | None = None):
         names = list(names)
-        out_pruned: list[str] = pruned_out if pruned_out is not None \
-            else []
         if not names:
-            return iter(()), out_pruned
+            return iter(())
         ops = list(ops)
         if not pipeline_mergeable(ops):
-            raise ValueError("exec_combine needs a decomposable pipeline "
-                             "whose tail has an associative merge")
+            raise ValueError("exec_combine_iter needs a decomposable "
+                             "pipeline whose tail has an associative merge")
         wire = _prune_wire(prune)
+        out_pruned: list[str] = pruned_out if pruned_out is not None \
+            else []
 
         def run_group(osd_id: str, idxs: list[int]) -> Any:
             try:
                 osd = self._osd(osd_id)
                 return osd.exec_cls_batch(
-                    [(names[i], ops) for i in idxs], combine=True,
+                    [(names[i], ops) for i in idxs], mode="combine",
                     prune=wire)
             except OSDDown as e:
                 return e
@@ -2099,25 +2059,18 @@ class ObjectStore:
                 self._client_xfer(rx)
                 emitted.append(merged)
             out_pruned.extend(pruned)
-            miss, bad = set(missing), set(corrupt)
-            retry = [i for i in idxs if names[i] in miss | bad]
-            for i in retry:
-                last_err[i] = CorruptObject(names[i]) \
-                    if names[i] in bad else ObjectNotFound(names[i])
-            return retry, emitted
+            return _fail_over(names, idxs, missing, corrupt,
+                              last_err), emitted
 
-        # dispatch order even when streaming: merged partials are a few
-        # bytes each, so there is no decode to overlap — but the fold
-        # over them is float-order-sensitive and must stay deterministic
-        gen = self._scatter_iter(names, run_group, handle, stream=stream,
-                                 completion_order=False)
-        return gen, out_pruned
+        return self._scatter_iter(names, run_group, handle,
+                                  completion_order=False)
 
-    def exec_concat(self, names: Iterable[str],
-                    ops: list[ObjOp] | Sequence[list[ObjOp]],
-                    prune=None) -> tuple[list, list[str]]:
+    def exec_concat_iter(self, names: Iterable[str],
+                         ops: list[ObjOp] | Sequence[list[ObjOp]],
+                         prune=None, pruned_out: list | None = None
+                         ) -> Iterator[tuple]:
         """Batched pushdown with SERVER-SIDE table concat — the
-        table-out twin of ``exec_combine``.
+        table-out twin of ``exec_combine_iter``.
 
         Each involved OSD runs its items' (table-out) pipelines over
         local data, concatenates the per-object result tables, and
@@ -2125,60 +2078,33 @@ class ObjectStore:
         over N objects on K OSDs moves exactly K framed responses
         (``rx_frames`` O(K)) instead of N.  ``ops`` is one shared
         pipeline or a per-object sequence (``len == len(names)``),
-        mirroring ``exec_batch``.
+        mirroring ``exec_batch_iter``.
 
-        Returns ``(frames, pruned_names)`` where each frame is
-        ``(input_indices, blob, row_counts)``: the indices (into
+        Yields each frame ``(input_indices, blob, row_counts)`` the
+        moment its response lands (completion order): the indices (into
         ``names``) this frame serves, in the order their rows appear in
         the concatenated block, with ``row_counts[j]`` rows belonging
         to ``indices[j]`` — everything the client needs to re-slice the
         block into per-object tables and restore global row order.
-        ``prune`` behaves exactly as in ``exec_combine`` (OSD-side
-        zone-map skip against current xattrs, no replica retry).
-        Missing objects fail over to the next replica as fresh batched
-        requests.
+        ``prune`` and ``pruned_out`` behave exactly as in
+        ``exec_combine_iter`` (OSD-side zone-map skip against current
+        xattrs, no replica retry).  Missing objects fail over to the
+        next replica as fresh batched requests.
         """
-        gen, pruned_out = self._exec_concat_impl(names, ops, prune)
-        return list(gen), pruned_out
-
-    def exec_concat_iter(self, names: Iterable[str],
-                         ops: list[ObjOp] | Sequence[list[ObjOp]],
-                         prune=None, pruned_out: list | None = None
-                         ) -> Iterator[tuple]:
-        """Streaming twin of ``exec_concat``: yields each OSD's framed
-        block ``(input_indices, blob, row_counts)`` the moment its
-        response lands (completion order), so the client decodes early
-        frames while slower OSDs are still scanning — the scan-side
-        half of the windowed overlap (delivered frames count in
-        ``Fabric.stream_windows``).  OSD-pruned names accumulate into
-        ``pruned_out`` (complete once the iterator is exhausted)."""
-        gen, _ = self._exec_concat_impl(names, ops, prune, stream=True,
-                                        pruned_out=pruned_out)
-        return gen
-
-    def _exec_concat_impl(self, names, ops, prune, stream: bool = False,
-                          pruned_out: list | None = None):
         names = list(names)
+        if not names:
+            return iter(())
+        pipelines = _per_object(names, ops)
+        wire = _prune_wire(prune)
         out_pruned: list[str] = pruned_out if pruned_out is not None \
             else []
-        if not names:
-            return iter(()), out_pruned
-        if ops and isinstance(ops[0], (list, tuple)):
-            pipelines = [list(p) for p in ops]
-            if len(pipelines) != len(names):
-                raise ValueError(
-                    f"{len(pipelines)} pipelines for {len(names)} objects")
-        else:
-            pipelines = [list(ops)] * len(names)
-
-        wire = _prune_wire(prune)
 
         def run_group(osd_id: str, idxs: list[int]) -> Any:
             try:
                 osd = self._osd(osd_id)
                 return osd.exec_cls_batch(
                     [(names[i], pipelines[i]) for i in idxs],
-                    concat=True, prune=wire)
+                    mode="concat", prune=wire)
             except OSDDown as e:
                 return e
 
@@ -2196,15 +2122,10 @@ class ObjectStore:
                 emitted.append(
                     (tuple(idxs[k] for k in served), blob, counts))
             out_pruned.extend(pruned)
-            miss, bad = set(missing), set(corrupt)
-            retry = [i for i in idxs if names[i] in miss | bad]
-            for i in retry:
-                last_err[i] = CorruptObject(names[i]) \
-                    if names[i] in bad else ObjectNotFound(names[i])
-            return retry, emitted
+            return _fail_over(names, idxs, missing, corrupt,
+                              last_err), emitted
 
-        gen = self._scatter_iter(names, run_group, handle, stream=stream)
-        return gen, out_pruned
+        return self._scatter_iter(names, run_group, handle)
 
     def delete(self, name: str) -> None:
         for osd_id in self.cluster.up_osds:
@@ -2682,6 +2603,29 @@ def _prune_wire(prune):
     normalized, so row semantics are untouched."""
     pred = ex.normalize(ex.ensure_pred(prune))
     return None if pred is None else pred.to_json()
+
+
+def _per_object(names: list[str], ops) -> list[list[ObjOp]]:
+    """One pipeline per name: ``ops`` is one shared pipeline or a
+    per-object sequence of them (``len == len(names)``)."""
+    if ops and isinstance(ops[0], (list, tuple)):
+        pipelines = [list(p) for p in ops]
+        if len(pipelines) != len(names):
+            raise ValueError(
+                f"{len(pipelines)} pipelines for {len(names)} objects")
+        return pipelines
+    return [list(ops)] * len(names)
+
+
+def _fail_over(names, idxs, missing, corrupt, last_err) -> list[int]:
+    """The items of one combine/concat response to retry on their next
+    replica — missing or quarantined HERE — with each one's error."""
+    miss, bad = set(missing), set(corrupt)
+    retry = [i for i in idxs if names[i] in miss | bad]
+    for i in retry:
+        last_err[i] = CorruptObject(names[i]) \
+            if names[i] in bad else ObjectNotFound(names[i])
+    return retry
 
 
 def _result_nbytes(result: Any) -> int:

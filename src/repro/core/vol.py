@@ -15,12 +15,13 @@ Every interaction rides the store's symmetric per-OSD batch plane:
 writes go through ``ObjectStore.put_batch`` (one request per primary
 OSD — windowed/streaming when transfers take simulated time, so the
 per-object encode overlaps the NIC stream); compiled plans execute
-through the streaming consume of ``exec_combine`` (aggregate tails:
-one partial per OSD), ``exec_concat`` (table-out tails: ONE framed
-table response per OSD, decoded frame-by-frame as they land), or
-``exec_batch`` (per-object results) — fabric ops AND result frames
-scale with the number of OSDs, not the number of objects, on every
-path, and wall clock scales with the slowest OSD, not the sum.
+through the streaming consume of ``exec_combine_iter`` (aggregate
+tails: one partial per OSD), ``exec_concat_iter`` (table-out tails:
+ONE framed table response per OSD, decoded frame-by-frame as they
+land), or ``exec_batch_iter`` (per-object results) — fabric ops AND
+result frames scale with the number of OSDs, not the number of
+objects, on every path, and wall clock scales with the slowest OSD,
+not the sum.
 
 Pruning is pushed down by default: the filter expression TREE
 (``core.expr`` — OR-groups, IN-lists, ranges, prefixes, negations)
@@ -126,7 +127,7 @@ class GlobalVOL:
         self.local = local or LocalVOL()
         self.workers = workers
         # the ONE plan→compile→execute surface (core.scan); read/query/
-        # scan and the Skyhook driver all route through it
+        # scan all route through it
         self.engine = ScanEngine(self)
         # client-side zone-map cache, keyed by cluster-map epoch:
         # name -> (zone_map, version-it-was-read-at).  Warmed in one
@@ -412,7 +413,7 @@ class GlobalVOL:
              columns: list[str] | None = None) -> dict[str, np.ndarray]:
         """Gather a row range; per-object select+project run storage-side
         so only requested rows/columns move, and each OSD concatenates
-        its result tables into ONE framed response (``exec_concat``)."""
+        its result tables into ONE framed response (``exec_concat_iter``)."""
         plan = self.engine.compile_read(omap, rows, columns)
         table, _ = self.engine.execute(plan, omap=omap)
         return table

@@ -101,8 +101,7 @@ def make_fused_train_step(base_train_step):
     """Wrap a (state, batch)->(state, metrics) step to take packed words.
 
     The unpack lands inside the same XLA program, so cost_analysis of the
-    fused step shows the input-bytes reduction directly (benchmarked in
-    benchmarks/ingest_fused.py).
+    fused step shows the input-bytes reduction directly.
     """
 
     def fused_step(state, packed):

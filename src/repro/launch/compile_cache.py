@@ -1,7 +1,7 @@
 """Where JAX keeps its persistent compilation cache.
 
 Entry points (``launch.train``, ``launch.serve``, ``examples/train_e2e``,
-``benchmarks.run``, ``chip_smoke.py``) call :func:`enable_compile_cache`
+``bench/run.py``, ``chip_smoke.py``) call :func:`enable_compile_cache`
 once, before their first compile; importing a module never does, and
 the test suite never does.  The rule:
 

@@ -40,7 +40,7 @@ from repro.train.optimizer import OptConfig
 from repro.train.steps import abstract_train_state, make_train_step
 
 RESULTS_DIR = pathlib.Path(__file__).resolve().parents[3] / \
-    "benchmarks" / "results" / "dryrun"
+    "results" / "dryrun"
 
 # TPU v5e-class constants (assignment)
 PEAK_FLOPS = 197e12          # bf16 / chip

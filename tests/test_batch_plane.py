@@ -1,5 +1,5 @@
 """Symmetric per-OSD batch plane: batched writes (put_batch), server-
-side per-OSD combine (exec_combine), batched zone-map metadata
+side per-OSD combine (exec_combine_iter), batched zone-map metadata
 (list_zone_maps), and the cross-client version-tag coherence protocol.
 Example-based on purpose: must run without hypothesis."""
 
@@ -7,10 +7,11 @@ import numpy as np
 import pytest
 
 from repro.core import (Column, GlobalVOL, LogicalDataset, PartitionPolicy,
-                        Query, SkyhookDriver, make_store)
+                        make_store)
 from repro.core import expr as ex
 from repro.core import format as fmt
 from repro.core import objclass as oc
+from repro.core.scan import _by_position
 from repro.core.store import OSDDown, PER_REQUEST_OVERHEAD_BYTES
 
 
@@ -25,6 +26,11 @@ def make_world(n=4000, n_osds=5, replicas=3, seed=0):
     table = {"x": rng.normal(size=n),
              "y": rng.integers(0, 1000, n).astype(np.int32)}
     return store, vol, omap, table
+
+
+def batch_results(store, names, ops):
+    """The batch plane's results placed at their input positions."""
+    return _by_position(len(names), store.exec_batch_iter(names, ops))
 
 
 # -------------------------------------------------------------- put_batch
@@ -185,8 +191,8 @@ def test_exec_combine_equals_client_side_combine(tail, fn, col):
     names = omap.object_names()
     ops = [oc.op("filter", col="y", cmp="<", value=500),
            oc.op(tail, col=col, fn=fn)]
-    per_object = store.exec_batch(names, ops)
-    merged = store.exec_combine(names, ops)
+    per_object = batch_results(store, names, ops)
+    merged = list(store.exec_combine_iter(names, ops))
     # one partial per OSD, not per object
     primaries = {store.cluster.primary(n) for n in names}
     assert len(merged) <= len(primaries) < len(per_object)
@@ -206,8 +212,8 @@ def test_exec_combine_quantile_sketch_tail():
     vol.write(omap, table)
     names = omap.object_names()
     ops = [oc.op("quantile_sketch", col="x", lo=-6.0, hi=6.0)]
-    merged = store.exec_combine(names, ops)
-    per_object = store.exec_batch(names, ops)
+    merged = list(store.exec_combine_iter(names, ops))
+    per_object = batch_results(store, names, ops)
     assert oc.combine_partials(ops, merged) == pytest.approx(
         oc.combine_partials(ops, per_object), rel=1e-12)
 
@@ -220,10 +226,10 @@ def test_exec_combine_client_rx_is_o_k():
     primaries = {store.cluster.primary(n) for n in names}
 
     store.fabric.reset()
-    store.exec_combine(names, ops)
+    list(store.exec_combine_iter(names, ops))
     combined = store.fabric.snapshot()
     store.fabric.reset()
-    store.exec_batch(names, ops)
+    batch_results(store, names, ops)
     batched = store.fabric.snapshot()
 
     assert combined["ops"] == batched["ops"] == len(primaries)
@@ -238,7 +244,8 @@ def test_exec_combine_failover_to_replica_mid_batch():
     vol.write(omap, table)
     names = omap.object_names()
     ops = [oc.op("agg", col="x", fn="sum")]
-    expect = oc.combine_partials(ops, store.exec_combine(names, ops))
+    expect = oc.combine_partials(
+        ops, list(store.exec_combine_iter(names, ops)))
     # primary silently lost one object: its partial must come from a
     # replica (as a second, batched, request) and the total must match
     victim = names[0]
@@ -246,7 +253,7 @@ def test_exec_combine_failover_to_replica_mid_batch():
     with store.osds[primary].lock:
         del store.osds[primary].data[victim]
     store.fabric.reset()
-    merged = store.exec_combine(names, ops)
+    merged = list(store.exec_combine_iter(names, ops))
     primaries = {store.cluster.primary(n) for n in names}
     assert store.fabric.ops == len(primaries) + 1  # + one retry request
     assert oc.combine_partials(ops, merged) == pytest.approx(expect,
@@ -261,14 +268,15 @@ def test_exec_combine_raises_when_all_replicas_lost():
         with osd.lock:
             osd.data.pop(name, None)
     with pytest.raises(KeyError):
-        store.exec_combine([name], [oc.op("agg", col="x", fn="sum")])
+        list(store.exec_combine_iter([name],
+                                     [oc.op("agg", col="x", fn="sum")]))
 
 
 def test_exec_combine_rejects_non_mergeable_pipeline():
     store, vol, omap, table = make_world()
     with pytest.raises(ValueError):
-        store.exec_combine(omap.object_names(),
-                           [oc.op("median", col="x")])
+        list(store.exec_combine_iter(omap.object_names(),
+                                     [oc.op("median", col="x")]))
 
 
 def test_query_and_driver_use_per_osd_combine():
@@ -280,13 +288,11 @@ def test_query_and_driver_use_per_osd_combine():
     assert stats["ops"] <= len(primaries)
     assert stats["client_rx"] <= len(primaries) * 16
 
-    drv = SkyhookDriver(vol, n_workers=3)
-    r, s = drv.execute(Query("t", filter=("y", "<", 500),
-                             aggregate=("mean", "x")))
+    r, s = vol.scan("t").filter("y", "<", 500).agg("mean", "x").execute()
     assert r == pytest.approx(table["x"][table["y"] < 500].mean(),
                               rel=1e-12)
-    assert s.fabric_ops <= len(primaries)
-    assert s.client_rx_bytes <= len(primaries) * 16
+    assert s["ops"] <= len(primaries)
+    assert s["client_rx"] <= len(primaries) * 16
 
 
 # --------------------------------------------------- zone-map metadata

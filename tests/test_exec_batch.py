@@ -1,4 +1,4 @@
-"""Batched scatter-gather data plane (exec_batch + column pruning +
+"""Batched scatter-gather data plane (exec_batch_iter + column pruning +
 vectorized bitpack codec).  Example-based on purpose: this module must
 run even when hypothesis is unavailable."""
 
@@ -6,11 +6,11 @@ import numpy as np
 import pytest
 
 from repro.core import (Column, GlobalVOL, LogicalDataset, PartitionPolicy,
-                        Query, RowRange, SkyhookDriver, make_store)
+                        RowRange, make_store)
 from repro.core import expr as ex
 from repro.core import format as fmt
 from repro.core import objclass as oc
-from repro.core.scan import Scan
+from repro.core.scan import Scan, _by_position
 from repro.core.store import PER_REQUEST_OVERHEAD_BYTES
 
 
@@ -34,11 +34,16 @@ FILTER_AGG = [oc.op("filter", col="y", cmp="<", value=500),
               oc.op("agg", col="x", fn="sum")]
 
 
-# ------------------------------------------------------------- exec_batch
+def batch_results(store, names, ops):
+    """The batch plane's results placed at their input positions."""
+    return _by_position(len(names), store.exec_batch_iter(names, ops))
+
+
+# -------------------------------------------------------- exec_batch_iter
 def test_exec_batch_results_match_per_object_exec():
     store, vol, omap, table = make_world()
     names = omap.object_names()
-    batch = store.exec_batch(names, FILTER_AGG)
+    batch = batch_results(store, names, FILTER_AGG)
     single = [store.exec(n, FILTER_AGG) for n in names]
     assert len(batch) == len(single)
     for b, s in zip(batch, single):
@@ -53,7 +58,7 @@ def test_exec_batch_one_request_per_osd_and_same_bytes():
     primaries = {store.cluster.primary(n) for n in names}
 
     store.fabric.reset()
-    store.exec_batch(names, FILTER_AGG)
+    batch_results(store, names, FILTER_AGG)
     batched = store.fabric.snapshot()
 
     store.fabric.reset()
@@ -76,17 +81,17 @@ def test_exec_batch_per_object_pipelines():
     store, vol, omap, table = make_world()
     names = omap.object_names()[:3]
     pipelines = [[oc.op("select", rows=(0, k + 1))] for k in range(3)]
-    blobs = store.exec_batch(names, pipelines)
+    blobs = batch_results(store, names, pipelines)
     for k, blob in enumerate(blobs):
         assert fmt.block_header(blob)["n_rows"] == k + 1
     with pytest.raises(ValueError):
-        store.exec_batch(names, pipelines[:2])
+        batch_results(store, names, pipelines[:2])
 
 
 def test_exec_batch_failover_to_replica_mid_batch():
     store, vol, omap, table = make_world()
     names = omap.object_names()
-    expect = store.exec_batch(names, FILTER_AGG)
+    expect = batch_results(store, names, FILTER_AGG)
 
     # primary silently lost one object: that item must fail over to a
     # replica inside the batch while everything else stays batched
@@ -95,7 +100,7 @@ def test_exec_batch_failover_to_replica_mid_batch():
     with store.osds[primary].lock:
         del store.osds[primary].data[victim]
     store.fabric.reset()
-    got = store.exec_batch(names, FILTER_AGG)
+    got = batch_results(store, names, FILTER_AGG)
     for g, e in zip(got, expect):
         for k in e:
             assert np.array_equal(g[k], e[k])
@@ -106,9 +111,9 @@ def test_exec_batch_failover_to_replica_mid_batch():
 def test_exec_batch_failover_on_osd_failure():
     store, vol, omap, table = make_world()
     names = omap.object_names()
-    expect = store.exec_batch(names, FILTER_AGG)
+    expect = batch_results(store, names, FILTER_AGG)
     store.fail_osd(store.cluster.primary(names[0]))
-    got = store.exec_batch(names, FILTER_AGG)
+    got = batch_results(store, names, FILTER_AGG)
     for g, e in zip(got, expect):
         for k in e:
             assert np.array_equal(g[k], e[k])
@@ -121,7 +126,43 @@ def test_exec_batch_raises_when_all_replicas_lost():
         with osd.lock:
             osd.data.pop(name, None)
     with pytest.raises(KeyError):
-        store.exec_batch([name], FILTER_AGG)
+        batch_results(store, [name], FILTER_AGG)
+
+
+@pytest.mark.parametrize("mode", ["batch", "combine", "concat", "gather"])
+def test_exec_cls_batch_serves_each_mode_and_rejects_unknown(mode):
+    """One OSD request per mode: per-item results (batch), one merged
+    partial (combine), one framed table (concat); any other mode is
+    refused."""
+    store, vol, omap, table = make_world()
+    names = omap.object_names()
+    osd_id = store.cluster.primary(names[0])
+    mine = [n for n in names if store.cluster.primary(n) == osd_id]
+    osd = store.osds[osd_id]
+    ops = {"batch": FILTER_AGG, "combine": FILTER_AGG,
+           "concat": [oc.op("project", cols=["y"])]}.get(mode, FILTER_AGG)
+    items = [(n, ops) for n in mine]
+    if mode == "gather":
+        with pytest.raises(ValueError):
+            osd.exec_cls_batch(items, mode=mode)
+        return
+    got = osd.exec_cls_batch(items, mode=mode)
+    if mode == "batch":
+        results, _ = got
+        assert [r for r, _ in results] == [store.exec(n, ops) for n in mine]
+    elif mode == "combine":
+        merged, n_found = got[:2]
+        assert n_found == len(mine)
+        assert oc.combine_partials(ops, [merged]) == pytest.approx(
+            oc.combine_partials(ops, [store.exec(n, ops) for n in mine]),
+            rel=1e-12)
+    else:
+        blob, served, counts = got[:3]
+        assert served == tuple(range(len(mine)))
+        assert np.array_equal(
+            fmt.decode_block(blob)["y"],
+            np.concatenate([fmt.decode_block(store.exec(n, ops))["y"]
+                            for n in mine]))
 
 
 def test_query_ops_bounded_by_osds_not_objects():
@@ -135,12 +176,10 @@ def test_query_ops_bounded_by_osds_not_objects():
 
 def test_driver_query_ops_bounded_and_correct():
     store, vol, omap, table = make_world()
-    drv = SkyhookDriver(vol, n_workers=3)
-    q = Query("t", filter=("y", "<", 500), aggregate=("mean", "x"))
-    r, s = drv.execute(q)
+    r, s = vol.scan("t").filter("y", "<", 500).agg("mean", "x").execute()
     assert r == pytest.approx(table["x"][table["y"] < 500].mean(),
                               rel=1e-12)
-    assert s.fabric_ops <= len(store.cluster.up_osds)
+    assert s["ops"] <= len(store.cluster.up_osds)
 
 
 def test_read_through_batch_equals_slice():

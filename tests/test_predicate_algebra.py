@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from repro.core import (Column, GlobalVOL, LogicalDataset, PartitionPolicy,
-                        RowRange, SkyhookDriver, make_store)
+                        RowRange, make_store)
 from repro.core import expr as ex
 from repro.core import format as fmt
 from repro.core import objclass as oc
@@ -69,13 +69,12 @@ def test_expression_scans_match_client_filtering_bit_exact():
     """Every expression node, through the pushed-down plane vs the
     no-pushdown client baseline vs prune='none': identical rows."""
     store, vol, omap, table = make_world()
-    drv = SkyhookDriver(vol, n_workers=3)
     for build, mask in _cases(table):
         s = build(vol.scan("t")).project("x", "y")
         r_push, st_push = s.execute()
         r_none, _ = s.prune("none").execute()
-        r_base, _ = drv.execute_client_side(build(drv.scan("t"))
-                                            .project("x", "y"))
+        r_base, _ = vol.engine.execute(vol.engine.compile_ops(
+            omap, s.pipeline(), baseline=True))
         for k in ("x", "y"):
             assert np.array_equal(r_push[k], table[k][mask])
             assert np.array_equal(r_none[k], table[k][mask])
@@ -105,13 +104,12 @@ def test_or_in_scan_zero_zone_map_requests_and_k_frames():
 
 def test_driver_schedules_expression_scans():
     store, vol, omap, table = make_world()
-    drv = SkyhookDriver(vol, n_workers=3)
-    r, qs = drv.execute(drv.scan("t").or_(("y", "<", 10), ("y", ">", 990))
-                        .agg("sum", "x"))
+    r, qs = (vol.scan("t").or_(("y", "<", 10), ("y", ">", 990))
+             .agg("sum", "x").execute())
     mask = (table["y"] < 10) | (table["y"] > 990)
     assert r == pytest.approx(table["x"][mask].sum(), rel=1e-12)
-    assert qs.prune == "pushdown"
-    assert qs.exec_class == sc.EXEC_OSD_COMBINE
+    assert qs["prune"] == "pushdown"
+    assert qs["exec_class"] == sc.EXEC_OSD_COMBINE
 
 
 # ------------------------------------------------------- prune algebra
@@ -207,8 +205,9 @@ def test_legacy_triple_prune_payloads_still_work():
     names = omap.object_names()
     ops = [oc.op("filter", col="y", cmp=">", value=5000),
            oc.op("agg", col="y", fn="count")]
-    partials, pruned = store.exec_combine(
-        names, ops, prune=(("y", ">", 5000),))
+    pruned = []
+    partials = list(store.exec_combine_iter(
+        names, ops, prune=(("y", ">", 5000),), pruned_out=pruned))
     assert not partials and set(pruned) == set(names)
 
 

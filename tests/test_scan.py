@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core import (Column, GlobalVOL, LogicalDataset, PartitionPolicy,
-                        Query, RowRange, Scan, SkyhookDriver, make_store)
+                        RowRange, Scan, make_store)
 from repro.core import expr as ex
 from repro.core import format as fmt
 from repro.core import objclass as oc
@@ -26,6 +26,11 @@ def make_world(n=4000, n_osds=5, replicas=3, seed=0):
              "y": rng.integers(0, 1000, n).astype(np.int32)}
     vol.write(omap, table)
     return store, vol, omap, table
+
+
+def batch_results(store, names, ops):
+    """The batch plane's results placed at their input positions."""
+    return sc._by_position(len(names), store.exec_batch_iter(names, ops))
 
 
 IMPOSSIBLE = [oc.op("filter", col="y", cmp=">", value=2000),
@@ -152,44 +157,20 @@ def test_explain_exposes_physical_plan():
     assert plan.prune == "pushdown"
     assert plan.predicates == ex.Cmp("y", "<", 500)
     assert len(plan.names) == omap.n_objects
-    assert {o for o, _ in plan.shards} <= set(store.cluster.up_osds)
-    assert sum(len(i) for _, i in plan.shards) == omap.n_objects
 
 
-# ---------------------------------------------------------- query shim
-def test_query_shim_compiles_to_scan():
-    q = Query("t", filter=("y", "<", 500), projection=("x",),
-              aggregate=("mean", "x"))
-    ops = q.pipeline()
-    assert [o.name for o in ops] == ["filter", "project", "agg"]
-    # N filters: explicit field, or a sequence in the legacy slot —
-    # both compile to ONE filter op carrying the conjunction tree
-    q2 = Query("t", filters=(("y", ">", 1), ("y", "<", 9)))
-    (f2,) = q2.pipeline()
-    assert f2.name == "filter"
-    assert ex.from_json(f2.params["expr"]) == ex.And(
-        (ex.Cmp("y", ">", 1), ex.Cmp("y", "<", 9)))
-    q3 = Query("t", filter=(("y", ">", 1), ("y", "<", 9)))
-    assert q3.pipeline() == q2.pipeline()
-    # N aggregates compile to one mergeable multi_agg tail
-    q4 = Query("t", aggregate=(("sum", "x"), ("count", "x")))
-    assert q4.pipeline()[-1].name == "multi_agg"
-
-
+# ------------------------------------------------------ multi-filter
 def test_query_shim_multi_filter_end_to_end():
     store, vol, omap, table = make_world()
-    drv = SkyhookDriver(vol, n_workers=3)
-    q = Query("t", filters=(("y", ">", 100), ("y", "<", 300)),
-              aggregate=("count", "x"))
-    res, stats = drv.execute(q)
+    res, stats = (vol.scan("t").filter("y", ">", 100).filter("y", "<", 300)
+                  .agg("count", "x").execute())
     mask = (table["y"] > 100) & (table["y"] < 300)
     assert res == float(mask.sum())
-    assert stats.pushdown and stats.result_rows == 1
+    assert stats["pushdown"] and stats["result_rows"] == 1
     # conjunction prunes: a range wholly outside every zone map
-    q_imp = Query("t", filters=(("y", ">", 100), ("y", ">", 2000)),
-                  aggregate=("count", "x"))
-    res, stats = drv.execute(q_imp)
-    assert res == 0.0 and stats.objects_pruned == omap.n_objects
+    res, stats = (vol.scan("t").filter("y", ">", 100).filter("y", ">", 2000)
+                  .agg("count", "x").execute())
+    assert res == 0.0 and stats["objects_pruned"] == omap.n_objects
 
 
 # ------------------------------------------------- OSD-side prune plane
@@ -288,10 +269,11 @@ def test_exec_concat_matches_exec_batch_bit_exact():
     names = omap.object_names()
     ops = [oc.op("filter", col="y", cmp="<", value=500),
            oc.op("project", cols=["x", "y"])]
-    frames, pruned = store.exec_concat(names, ops)
+    pruned = []
+    frames = list(store.exec_concat_iter(names, ops, pruned_out=pruned))
     assert not pruned
     parts = sc._split_frames(len(names), frames)
-    blobs = store.exec_batch(names, ops)
+    blobs = batch_results(store, names, ops)
     for part, blob in zip(parts, blobs):
         ref = fmt.decode_block(blob)
         assert set(part) == set(ref)
@@ -304,13 +286,13 @@ def test_exec_concat_failover_to_replica_mid_batch():
     names = omap.object_names()
     ops = [oc.op("project", cols=["y"])]
     expect = np.concatenate(
-        [fmt.decode_block(b)["y"] for b in store.exec_batch(names, ops)])
+        [fmt.decode_block(b)["y"] for b in batch_results(store, names, ops)])
     victim = names[0]
     primary = store.cluster.primary(victim)
     with store.osds[primary].lock:
         del store.osds[primary].data[victim]
     store.fabric.reset()
-    frames, _ = store.exec_concat(names, ops)
+    frames = list(store.exec_concat_iter(names, ops))
     primaries = {store.cluster.primary(n) for n in names}
     assert store.fabric.ops == len(primaries) + 1  # + one retry request
     parts = sc._split_frames(len(names), frames)
@@ -321,8 +303,8 @@ def test_exec_concat_failover_to_replica_mid_batch():
 def test_exec_concat_rejects_partial_tails():
     store, vol, omap, table = make_world()
     with pytest.raises(ValueError):
-        store.exec_concat(omap.object_names(),
-                          [oc.op("agg", col="x", fn="sum")])
+        list(store.exec_concat_iter(omap.object_names(),
+                                    [oc.op("agg", col="x", fn="sum")]))
 
 
 def test_read_rides_concat_plane():
@@ -336,65 +318,57 @@ def test_read_rides_concat_plane():
 
 # ---------------------------------------------------- unified stats
 def test_stats_drift_fixed_between_vol_and_driver():
-    """Same scan, same stats: the holistic+approx rewrite used to report
-    pushdown=True via vol.query but False via the driver."""
+    """Same scan, same stats: the holistic+approx rewrite reports the
+    same pushdown through vol.query as through Scan.execute."""
     store, vol, omap, table = make_world()
-    drv = SkyhookDriver(vol, n_workers=3)
     _, vs = vol.query(omap, [oc.op("median", col="x")], allow_approx=True)
-    _, ds = drv.execute(Query("t", aggregate=("median", "x"),
-                              allow_approx=True))
-    assert vs["pushdown"] is True and ds.pushdown is True
-    assert vs["approx_rewrite"] and ds.exec_class == sc.EXEC_OSD_COMBINE
+    _, ds = vol.scan("t").median("x", approx=True).execute()
+    assert vs["pushdown"] is True and ds["pushdown"] is True
+    assert vs["approx_rewrite"] and ds["exec_class"] == sc.EXEC_OSD_COMBINE
     _, vs2 = vol.query(omap, [oc.op("median", col="x")])
-    _, ds2 = drv.execute(Query("t", aggregate=("median", "x")))
-    assert vs2["pushdown"] is False and ds2.pushdown is False
+    _, ds2 = vol.scan("t").median("x").execute()
+    assert vs2["pushdown"] is False and ds2["pushdown"] is False
 
 
 def test_result_rows_never_none_for_completed_queries():
     store, vol, omap, table = make_world()
-    drv = SkyhookDriver(vol, n_workers=2)
-    _, s = drv.execute(Query("t", aggregate=("sum", "x")))
-    assert s.result_rows == 1                     # scalar aggregate
-    _, s = drv.execute(Query("t", aggregate=("median", "x")))
-    assert s.result_rows == 1                     # holistic scalar
-    _, s = drv.execute(Query("t", aggregate=(("sum", "x"),
-                                             ("count", "y"))))
-    assert s.result_rows == 1                     # one aggregate row
-    _, s = drv.execute(Query("t", filter=("y", "<", 50),
-                             projection=("x",)))
-    assert s.result_rows == int((table["y"] < 50).sum())
-    _, s = drv.execute_client_side(Query("t", aggregate=("sum", "x")))
-    assert s.result_rows == 1                     # baseline, unified too
+    _, s = vol.scan("t").agg("sum", "x").execute()
+    assert s["result_rows"] == 1                  # scalar aggregate
+    _, s = vol.scan("t").median("x").execute()
+    assert s["result_rows"] == 1                  # holistic scalar
+    _, s = vol.scan("t").agg("sum", "x").agg("count", "y").execute()
+    assert s["result_rows"] == 1                  # one aggregate row
+    _, s = vol.scan("t").filter("y", "<", 50).project("x").execute()
+    assert s["result_rows"] == int((table["y"] < 50).sum())
+    _, s = vol.engine.execute(vol.engine.compile_ops(
+        omap, [oc.op("agg", col="x", fn="sum")], baseline=True))
+    assert s["result_rows"] == 1                  # baseline, unified too
 
 
 def test_driver_and_vol_execute_identical_plans():
     store, vol, omap, table = make_world()
-    drv = SkyhookDriver(vol, n_workers=3)
-    q = Query("t", filter=("y", "<", 300), aggregate=("mean", "x"))
-    r1, s1 = drv.execute(q)
+    q = vol.scan("t").filter("y", "<", 300).agg("mean", "x")
+    r1, s1 = q.execute()
     r2, vs = vol.query(omap, q.pipeline())
     assert r1 == pytest.approx(r2, rel=1e-15)
-    assert s1.exec_class == vs["exec_class"]
-    assert s1.prune == vs["prune"]
-    assert s1.fabric_ops == vs["ops"]
-    assert s1.rx_frames == vs["rx_frames"]
+    assert s1["exec_class"] == vs["exec_class"]
+    assert s1["prune"] == vs["prune"]
+    assert s1["ops"] == vs["ops"]
+    assert s1["rx_frames"] == vs["rx_frames"]
 
 
 def test_driver_table_out_preserves_row_order():
     store, vol, omap, table = make_world()
-    drv = SkyhookDriver(vol, n_workers=3)
-    res, _ = drv.execute(Query("t", filter=("y", "<", 50),
-                               projection=("x",)))
+    res, _ = vol.scan("t").filter("y", "<", 50).project("x").execute()
     assert np.array_equal(res["x"], table["x"][table["y"] < 50])
 
 
 def test_driver_executes_scans_directly():
     store, vol, omap, table = make_world()
-    drv = SkyhookDriver(vol, n_workers=2)
-    res, stats = drv.execute(drv.scan("t").filter("y", "<", 500)
-                             .agg("count", "x"))
+    res, stats = (Scan(dataset="t").bind(vol).filter("y", "<", 500)
+                  .agg("count", "x").execute())
     assert res == float((table["y"] < 500).sum())
-    assert stats.exec_class == sc.EXEC_OSD_COMBINE
+    assert stats["exec_class"] == sc.EXEC_OSD_COMBINE
 
 
 # ------------------------------------------- expression operands

@@ -246,7 +246,8 @@ def test_copy_without_extent_digests_is_checked_whole(copy):
             osd.data["o"] = blob[:-1] + bytes([blob[-1] ^ 1])
     store.fabric.reset()
     ops = [oc.op("agg", col="y", fn="sum")]
-    assert oc.combine_partials(ops, store.exec_batch(["o"], ops)) == \
+    assert oc.combine_partials(
+        ops, [r for _, r in store.exec_batch_iter(["o"], ops)]) == \
         t["y"].sum()
     assert store.fabric.corruptions_detected == 1
     # the whole blob on the rotten copy, then on the replica
@@ -367,23 +368,32 @@ def test_legacy_undigested_objects_are_reported_not_touched():
 @settings(max_examples=15, deadline=None)
 def test_scrub_is_idempotent_under_random_corruption(pattern):
     """Property: whatever (object, replica) set gets corrupted, one
-    healing scrub restores every survivor and a second scrub finds
+    healing scrub restores every object with a surviving copy, reports
+    every object with none in ``lost``, and a second scrub finds
     NOTHING (no corrupt copies, no heals) — scrub converges."""
     store, vol, omap, table = make_world()
     fi = FaultInjector(store)
     names = omap.object_names()
+    # each distinct copy is hit once: a second XOR would undo the first
+    hits: dict[str, set] = {}
     for obj_i, rep_i in pattern:
         name = names[obj_i % len(names)]
         acting = store.cluster.locate(name)
-        fi.flip_bits(name, osd_id=acting[rep_i % len(acting)])
+        hits.setdefault(name, set()).add(acting[rep_i % len(acting)])
+    for name, osd_ids in hits.items():
+        for osd_id in osd_ids:
+            fi.flip_bits(name, osd_id=osd_id)
+    lost = {n for n, osd_ids in hits.items()
+            if len(osd_ids) == len(store.cluster.locate(n))}
     first = store.scrub()
-    assert first["corrupt_copies"] == len({
-        (o % len(names), r) for o, r in pattern})
+    assert first["corrupt_copies"] == sum(len(v) for v in hits.values())
+    assert set(first["lost"]) == lost
     second = store.scrub()
     assert second["corrupt_copies"] == 0
     assert second["healed_copies"] == 0
     for name in names:
-        _copies_all_verify(store, name)
+        if name not in lost:
+            _copies_all_verify(store, name)
 
 
 # ------------------------------------------------------- verified recover

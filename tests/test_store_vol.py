@@ -5,7 +5,7 @@ import pytest
 from _hyp import given, settings, st
 
 from repro.core import (Column, GlobalVOL, LogicalDataset, PartitionPolicy,
-                        Query, RowRange, SkyhookDriver, make_store)
+                        RowRange, make_store)
 from repro.core import objclass as oc
 from repro.core.store import ObjectNotFound
 
@@ -131,20 +131,20 @@ def test_zone_map_pruning_sound_and_effective():
 
 def test_pushdown_vs_clientside_same_result_fewer_bytes():
     store, vol, omap, table = make_world()
-    drv = SkyhookDriver(vol, n_workers=3)
-    q = Query("t", filter=("y", "<", 300), aggregate=("mean", "x"))
-    r1, s1 = drv.execute(q)
-    r2, s2 = drv.execute_client_side(q)
+    q = vol.scan("t").filter("y", "<", 300).agg("mean", "x")
+    r1, s1 = q.execute()
+    r2, s2 = vol.engine.execute(
+        vol.engine.compile_ops(omap, q.pipeline(), baseline=True))
     assert r1 == pytest.approx(r2, rel=1e-12)
-    assert s1.client_rx_bytes < s2.client_rx_bytes / 20
-    assert s1.pushdown and not s2.pushdown
+    assert s1["client_rx"] < s2["client_rx"] / 20
+    assert s1["pushdown"] and not s2["pushdown"]
 
 
 def test_driver_table_pipeline_roundtrip():
     store, vol, omap, table = make_world()
-    drv = SkyhookDriver(vol, n_workers=2)
-    res, stats = drv.execute(Query("t", filter=("y", "<", 50),
-                                   projection=("x",)))
+    res, stats = vol.query(omap, [
+        oc.op("filter", col="y", cmp="<", value=50),
+        oc.op("project", cols=["x"])])
     expect = table["x"][table["y"] < 50]
     assert sorted(res["x"].tolist()) == sorted(expect.tolist())
 

@@ -11,6 +11,7 @@ import pytest
 
 from repro.core import (Column, GlobalVOL, LogicalDataset, PartitionPolicy,
                         RowRange, make_store)
+from repro.core import format as fmt
 from repro.core import objclass as oc
 from repro.core.store import OSDDown, PartialWriteError
 
@@ -299,12 +300,12 @@ def test_recover_heals_skipped_chain_hop():
 def test_exec_concat_iter_first_frame_before_slow_osd():
     """With one straggler OSD, the fast OSDs' frames must reach the
     consumer while the straggler is still scanning — and the assembled
-    result must be bit-exact vs the buffered gather."""
+    result must be bit-exact vs the per-object ``exec`` reference."""
     store, vol, omap, table = make_world(n_osds=4, replicas=2)
     vol.write(omap, table)
     names = omap.object_names()
     ops = [oc.op("project", cols=["y"])]
-    frames_ref, _ = store.exec_concat(names, ops)
+    ref = [fmt.decode_block(store.exec(n, ops))["y"] for n in names]
     primaries = {store.cluster.primary(n) for n in names}
     assert len(primaries) >= 3
 
@@ -322,10 +323,9 @@ def test_exec_concat_iter_first_frame_before_slow_osd():
     assert store.fabric.stream_windows == len(frames) == len(primaries)
 
     from repro.core.scan import _split_frames
-    parts_ref = _split_frames(len(names), frames_ref)
     parts = _split_frames(len(names), frames)
-    for a, b in zip(parts, parts_ref):
-        assert np.array_equal(a["y"], b["y"])
+    for a, b in zip(parts, ref):
+        assert np.array_equal(a["y"], b)
 
 
 def test_engine_execute_streams_frames_and_stats_count_windows():
@@ -351,12 +351,12 @@ def test_exec_batch_iter_matches_buffered_results():
     vol.write(omap, table)
     names = omap.object_names()
     ops = [oc.op("project", cols=["y"])]
-    buffered = store.exec_batch(names, ops)
+    reference = [store.exec(n, ops) for n in names]
     got: dict = {}
     for i, res in store.exec_batch_iter(names, ops):
         got[i] = res
     assert set(got) == set(range(len(names)))
-    for i, blob in enumerate(buffered):
+    for i, blob in enumerate(reference):
         assert got[i] == blob
 
 
@@ -365,7 +365,7 @@ def test_exec_combine_iter_failover_and_equivalence():
     vol.write(omap, table)
     names = omap.object_names()
     ops = [oc.op("agg", col="x", fn="sum")]
-    expect = oc.combine_partials(ops, store.exec_combine(names, ops))
+    expect = oc.combine_partials(ops, [store.exec(n, ops) for n in names])
     victim = names[0]
     primary = store.cluster.primary(victim)
     with store.osds[primary].lock:
